@@ -16,6 +16,10 @@ from .common import csv
 
 
 N_PODS = 4
+#: the serve() result fields each mode's row reports
+ROW_FIELDS = ("mode", "n_pods", "tokens", "tok_per_s", "invalidations_sent",
+              "invalidations_filtered", "coherence_bytes", "fetches",
+              "prefetched", "table_pages")
 
 
 def main(quick: bool = False) -> list:
@@ -24,8 +28,8 @@ def main(quick: bool = False) -> list:
         r = serve("qwen3_14b", n_requests=8 if quick else 24,
                   prompt_len=32, gen_len=8 if quick else 16, batch=4,
                   n_pods=N_PODS, mode=mode, verbose=False)
-        rows.append({k: (round(v, 1) if isinstance(v, float) else v)
-                     for k, v in r.items()})
+        rows.append({k: (round(r[k], 1) if isinstance(r[k], float)
+                         else r[k]) for k in ROW_FIELDS})
     # the budget-model row runs the same pod count as the serve rows above
     # (and carries it), so the eager/numapte ratio is comparable to them
     spec = BlockTableSpec(n_pods=N_PODS, n_tables=512)

@@ -2,7 +2,9 @@
 
 ``get_config(name)`` returns the full published config;
 ``get_smoke_config(name)`` returns a reduced same-family config for CPU
-smoke tests (small widths/layers/experts, same structural features).
+smoke tests (small widths/layers/experts, same structural features);
+``get_one_chip_config(name)`` the published widths cut to one TPU v5e chip
+(only archs whose module defines ``ONE_CHIP_CONFIG``).
 """
 from __future__ import annotations
 
@@ -35,16 +37,23 @@ SHAPES: Dict[str, ShapeSpec] = {
 }
 
 
+def _module(name: str):
+    return importlib.import_module(f".{name.replace('-', '_')}", __package__)
+
+
 def get_config(name: str) -> ModelConfig:
-    name = name.replace("-", "_")
-    mod = importlib.import_module(f".{name}", __package__)
-    return mod.CONFIG
+    return _module(name).CONFIG
 
 
 def get_smoke_config(name: str) -> ModelConfig:
-    name = name.replace("-", "_")
-    mod = importlib.import_module(f".{name}", __package__)
-    return mod.SMOKE_CONFIG
+    return _module(name).SMOKE_CONFIG
+
+
+def get_one_chip_config(name: str) -> ModelConfig:
+    mod = _module(name)
+    if not hasattr(mod, "ONE_CHIP_CONFIG"):
+        raise ValueError(f"{name} has no one-chip configuration")
+    return mod.ONE_CHIP_CONFIG
 
 
 def shape_cells(arch: str) -> List[str]:

@@ -6,7 +6,9 @@ maps logical names to mesh axes per deployment:
 
   * single-pod (16, 16) ('data', 'model')
   * multi-pod (2, 16, 16) ('pod', 'data', 'model') — 'pod' joins the batch
-    dimension (pure DP + the numaPTE coherence domain).
+    dimension (pure DP + the numaPTE coherence domain), and the KV pool is
+    split over ('pod', 'data') in lockstep with the batch, so each pod holds
+    the pools of its own sequences.
 
 This is the MaxText "logical axis rules" pattern, reduced to what we need.
 """
@@ -72,7 +74,7 @@ MULTI_POD_RULES = ShardingRules(rules=(
     ("vocab", "model"),
     ("experts", "model"),
     ("expert_ff", None),
-    ("blocks", "data"),
+    ("blocks", ("pod", "data")),
     ("pod", "pod"),
 ))
 
@@ -104,15 +106,15 @@ def logical_spec(*logical_axes: Optional[str]) -> P:
     return current_rules().spec(logical_axes)
 
 
+def get_active_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The mesh of the surrounding ``jax.set_mesh`` scope, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
 def _mesh_axes() -> frozenset:
-    try:
-        from ..jaxcompat import get_active_mesh
-        mesh = get_active_mesh()
-        if mesh is None:
-            return frozenset()
-        return frozenset(mesh.axis_names)
-    except Exception:
-        return frozenset()
+    mesh = get_active_mesh()
+    return frozenset() if mesh is None else frozenset(mesh.axis_names)
 
 
 def constrain(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:

@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the serving path (``flash_attention``,
+``paged_attention``, ``pte_gather``) plus the jitted ``fifo_miss`` loop.
+
+Each ``ops`` wrapper compiles its kernel for the TPU, and interprets it
+only where JAX's default backend is the CPU (``interpret_on_cpu``)."""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_on_cpu() -> bool:
+    """Whether a Pallas kernel runs in interpret mode: only where JAX's
+    default backend is the CPU, which is where the ops wrappers, traced
+    under ``jit``, place their operands and compile their programs."""
+    return jax.default_backend() == "cpu"

@@ -83,7 +83,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            window: Optional[int] = None,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: [B,H,S,hd]; k,v: [B,K,S,hd].  Returns [B,H,S,hd] f32."""
     B, H, S, hd = q.shape
     K = k.shape[1]

@@ -1,17 +1,13 @@
 """jit'd public wrapper for flash (prefill) attention."""
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
+from .. import interpret_on_cpu
 from .kernel import flash_attention_kernel
 from .ref import flash_attention_ref
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -20,4 +16,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if backend == "ref":
         return flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_kernel(q, k, v, causal=causal, window=window,
-                                  interpret=_interpret_default())
+                                  interpret=interpret_on_cpu())

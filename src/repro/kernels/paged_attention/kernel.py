@@ -92,7 +92,7 @@ def paged_attention_kernel(q: jax.Array, k_slabs: jax.Array,
                            v_slabs: jax.Array, block_tables: jax.Array,
                            seq_lens: jax.Array, *,
                            window: Optional[int] = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: [B,H,hd]; k/v_slabs: [N,bt,K,hd]; block_tables: [B,MB] physical
     frames; seq_lens: [B].  Returns [B,H,hd] float32."""
     B, H, hd = q.shape

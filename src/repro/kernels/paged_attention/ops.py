@@ -1,23 +1,18 @@
 """jit'd public wrapper for paged decode attention.
 
-On CPU (this container) the Pallas kernel runs in interpret mode; on TPU
-set ``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to compile the
-Mosaic kernel.  ``backend='ref'`` selects the jnp oracle — used by the
+The Pallas kernel is compiled for the TPU, and interpreted only where JAX's
+default backend is the CPU.  ``backend='ref'`` selects the jnp oracle — used by the
 dry-run lowering so XLA sees a pure-HLO path with identical semantics.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
+from .. import interpret_on_cpu
 from .kernel import paged_attention_kernel
 from .ref import paged_attention_ref
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def paged_attention(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
@@ -29,4 +24,4 @@ def paged_attention(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
                                    seq_lens, window=window)
     return paged_attention_kernel(q, k_slabs, v_slabs, block_tables,
                                   seq_lens, window=window,
-                                  interpret=_interpret_default())
+                                  interpret=interpret_on_cpu())

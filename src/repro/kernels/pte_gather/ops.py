@@ -1,17 +1,13 @@
 """jit'd public wrapper for the fused walk+prefetch kernel."""
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
 import jax
 
+from .. import interpret_on_cpu
 from .kernel import pte_gather_kernel
 from .ref import pte_gather_ref
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def pte_gather(entries: jax.Array, logical: jax.Array,
@@ -20,4 +16,4 @@ def pte_gather(entries: jax.Array, logical: jax.Array,
     if backend == "ref":
         return pte_gather_ref(entries, logical, prefetch_degree)
     return pte_gather_kernel(entries, logical, prefetch_degree,
-                             interpret=_interpret_default())
+                             interpret=interpret_on_cpu())

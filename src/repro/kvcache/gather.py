@@ -19,8 +19,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.sharding import current_rules
-from ..jaxcompat import get_active_mesh as _mesh, shard_map
+from ..distributed.sharding import current_rules, get_active_mesh as _mesh
+
+
+def _on_mesh(mesh, axis) -> bool:
+    """Whether ``mesh`` has every mesh axis that the pool axis ``axis``
+    (one name or a tuple, e.g. ('pod', 'data')) names."""
+    names = axis if isinstance(axis, tuple) else (axis,)
+    return mesh is not None and all(n in mesh.axis_names for n in names)
 
 
 def update_gather_plain(k_slabs: jax.Array, v_slabs: jax.Array,
@@ -86,7 +92,7 @@ def gather_readonly(k_stack: jax.Array, v_stack: jax.Array,
             vs = jax.lax.dynamic_index_in_dim(v_stack, layer_idx, 0, False)
             gather = jnp.where(phys_blocks >= 0, phys_blocks, 0)
             return ks[gather], vs[gather]
-        if mesh is None or data_ax not in mesh.axis_names:
+        if not _on_mesh(mesh, data_ax):
             L, P_, F = k_stack.shape[:3]
             pool_of = jnp.arange(phys_blocks.shape[0]) // max(
                 phys_blocks.shape[0] // P_, 1)
@@ -111,10 +117,10 @@ def gather_readonly(k_stack: jax.Array, v_stack: jax.Array,
             g = jnp.where(pb >= 0, pb, 0)
             return ks[g], vs[g]
 
-        f = shard_map(local, mesh=mesh,
-                      in_specs=(stack_spec, stack_spec, P(data_ax, None),
-                                P()),
-                      out_specs=(out_spec, out_spec), check_vma=False)
+        f = jax.shard_map(local, mesh=mesh,
+                          in_specs=(stack_spec, stack_spec, P(data_ax, None),
+                                    P()),
+                          out_specs=(out_spec, out_spec), check_vma=False)
         return f(k_stack, v_stack, phys_blocks, layer_idx)
 
 
@@ -171,7 +177,7 @@ def commit_token_writes(k_stack: jax.Array, v_stack: jax.Array,
     mesh = _mesh()
     rules = current_rules()
     data_ax = rules.lookup("blocks")
-    if mesh is None or data_ax not in mesh.axis_names:
+    if not _on_mesh(mesh, data_ax):
         P_, F = k_stack.shape[1:3]
         pool_of = jnp.arange(B) // max(B // P_, 1)
         gframe = frame + pool_of * F
@@ -191,10 +197,10 @@ def commit_token_writes(k_stack: jax.Array, v_stack: jax.Array,
         ks2, vs2 = _commit_plain(ks2, vs2, kn, vn, fr, sl, ok)
         return ks2[:, None], vs2[:, None]
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(stack_spec, stack_spec, new_spec, new_spec,
-                            P(data_ax), P(data_ax), P(data_ax)),
-                  out_specs=(stack_spec, stack_spec), check_vma=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(stack_spec, stack_spec, new_spec, new_spec,
+                                P(data_ax), P(data_ax), P(data_ax)),
+                      out_specs=(stack_spec, stack_spec), check_vma=False)
     return f(k_stack, v_stack, k_new, v_new, frame, slot, valid)
 
 
@@ -208,8 +214,8 @@ def update_gather_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
     is sharded over 'data' in lockstep with the pool axis."""
     mesh = _mesh()
     rules = current_rules()
-    data_ax = rules.lookup("blocks")  # pool axis: 'data'
-    if mesh is None or data_ax not in mesh.axis_names:
+    data_ax = rules.lookup("blocks")  # pool axes
+    if not _on_mesh(mesh, data_ax):
         # no mesh (smoke tests): collapse pools and run the plain path
         P_, F = k_slabs.shape[:2]
         pool_of = jnp.arange(phys_blocks.shape[0]) // max(
@@ -226,7 +232,7 @@ def update_gather_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
     hd_ax = rules.lookup("head_dim")
     kv_ax = rules.lookup("kv_heads")
     slab_spec = P(data_ax, None, None, kv_ax, hd_ax)
-    new_spec = P(rules.lookup("batch") if False else data_ax, kv_ax, hd_ax)
+    new_spec = P(data_ax, kv_ax, hd_ax)
     tbl_spec = P(data_ax, None)
 
     def local(ks, vs, kn, vn, pb, pos):
@@ -235,7 +241,7 @@ def update_gather_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
                                              block_tokens, fused_scope)
         return ks[None], vs[None], ka, va
 
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(slab_spec, slab_spec, new_spec, new_spec, tbl_spec,
                   P(data_ax)),
@@ -275,7 +281,7 @@ def decode_attention_sp(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
     scale = hd ** -0.5
     NEG = -2.0 ** 30
 
-    def local(q, ks, vs, pb, pos, lens, shard_idx, n_shards):
+    def local(q, ks, vs, pb, pos, lens, shard_idx):
         # ks/vs: [F_local, bt, K, hd]; pb: [B, MB_local] columns of my slice
         bt = block_tokens
         MBl = pb.shape[1]
@@ -321,7 +327,7 @@ def decode_attention_sp(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
         out = (gacc / jnp.maximum(gl, 1e-30)[..., None]).reshape(B, H, hd)
         return out, ks[None], vs[None]
 
-    if mesh is None or data_ax not in mesh.axis_names:
+    if not _on_mesh(mesh, data_ax):
         # single-device fallback: flatten pools and reuse the plain path
         P_, F = k_slabs.shape[:2]
         MB = phys_blocks.shape[1]
@@ -353,15 +359,14 @@ def decode_attention_sp(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
     # SP layout: slabs replicated over 'model' (the per-device share comes
     # from the 'data' split of the sequence), q replicated — the partial
     # softmax combine is the only cross-shard traffic.
-    n_shards = mesh.shape[data_ax]
     slab_spec = P(data_ax, None, None, None, None)
 
     def wrapper(q, ks, vs, pb, pos, lens):
         from jax import lax
         shard_idx = lax.axis_index(data_ax)
-        return local(q, ks[0], vs[0], pb, pos, lens, shard_idx, n_shards)
+        return local(q, ks[0], vs[0], pb, pos, lens, shard_idx)
 
-    f = shard_map(
+    f = jax.shard_map(
         wrapper, mesh=mesh,
         in_specs=(P(), slab_spec, slab_spec, P(None, data_ax), P(), P()),
         out_specs=(P(), slab_spec, slab_spec),
@@ -399,7 +404,7 @@ def scatter_prefill_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
     mesh = _mesh()
     rules = current_rules()
     data_ax = rules.lookup("blocks")
-    if mesh is None or data_ax not in mesh.axis_names:
+    if not _on_mesh(mesh, data_ax):
         P_, F = k_slabs.shape[:2]
         pool_of = jnp.arange(phys_blocks.shape[0]) // max(
             phys_blocks.shape[0] // P_, 1)
@@ -421,9 +426,9 @@ def scatter_prefill_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
                                        block_tokens)
         return ks[None], vs[None]
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(slab_spec, slab_spec, kv_spec, kv_spec,
-                            P(data_ax, None), P(data_ax, None)),
-                  out_specs=(slab_spec, slab_spec),
-                  check_vma=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(slab_spec, slab_spec, kv_spec, kv_spec,
+                                P(data_ax, None), P(data_ax, None)),
+                      out_specs=(slab_spec, slab_spec),
+                      check_vma=False)
     return f(k_slabs, v_slabs, k, v, phys_blocks, positions)

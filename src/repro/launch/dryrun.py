@@ -1,9 +1,8 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The ``os.environ`` line below MUST run before any other jax-touching import
-— jax locks the device count at first init, and the production meshes need
-512 host devices.  Smoke tests and benches never import this module, so
-they keep seeing 1 device.
+``main`` asks for 512 host devices (``XLA_FLAGS``) before jax first
+touches a backend — jax locks the device count at first init, and the
+production meshes need 512.  Importing this module changes nothing.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3_14b --shape train_4k
@@ -14,13 +13,10 @@ Each cell writes experiments/dryrun/<arch>__<shape>__<mesh>.json with
 memory analysis, cost analysis, collective schedule and roofline terms —
 benchmarks/roofline.py and EXPERIMENTS.md read from there.
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ruff: noqa: E402  (the XLA_FLAGS line must precede jax imports)
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import time
 import traceback
@@ -28,7 +24,6 @@ import traceback
 import jax
 
 from ..configs import ARCH_IDS, SHAPES, get_config, shape_cells
-from ..jaxcompat import set_mesh
 from .analysis import roofline_from_compiled
 from .mesh import make_production_mesh
 from .specs import build_cell
@@ -50,7 +45,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     cfg = get_config(arch)
     t0 = time.time()
     cell = build_cell(arch, shape, mesh, opts=opts)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.step_fn, donate_argnums=cell.donate)
         lowered = jitted.lower(*cell.args)
         t_lower = time.time() - t0
@@ -91,8 +86,17 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     return out
 
 
+def force_host_devices(n: int = 512) -> None:
+    """Ask XLA's CPU backend for ``n`` devices, keeping any other flags.
+    Takes effect only before jax first initializes a backend."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    os.environ["XLA_FLAGS"] = (
+        f"{flags} --xla_force_host_platform_device_count={n}".strip())
+
+
 def main() -> None:
     from .specs import PerfOptions
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -6,18 +6,14 @@
 Prints byte/flop/collective contributions per computation (trip-count
 weighted) and the heaviest instructions inside the top computations.
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ruff: noqa: E402
 import argparse
 import collections
 
 import jax
 
 from ..configs import ARCH_IDS, SHAPES
-from ..jaxcompat import set_mesh
 from . import hlo_analysis as H
+from .dryrun import force_host_devices
 from .mesh import make_production_mesh
 from .specs import PerfOptions, build_cell
 
@@ -83,6 +79,7 @@ def profile(hlo: str, n_devices: int, top: int = 10) -> None:
 
 
 def main() -> None:
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--shape", choices=list(SHAPES), required=True)
@@ -101,7 +98,7 @@ def main() -> None:
                        remat=args.remat)
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     cell = build_cell(args.arch, SHAPES[args.shape], mesh, opts=opts)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hlo = jax.jit(cell.step_fn, donate_argnums=cell.donate).lower(
             *cell.args).compile().as_text()
     profile(hlo, mesh.devices.size, args.top)

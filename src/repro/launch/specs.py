@@ -8,7 +8,6 @@ full-scale cells on a 512-device host mesh without materializing a byte.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -19,8 +18,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs import ShapeSpec, get_config
 from ..distributed.sharding import (MULTI_POD_RULES, SINGLE_POD_RULES,
-                                    ShardingRules, param_pspec, use_rules)
-from ..jaxcompat import get_active_mesh, shard_map
+                                    ShardingRules, get_active_mesh,
+                                    param_pspec, use_rules)
 from ..models import (init_decode_state, init_params, layer_groups, lm_loss)
 from ..models.common import ModelConfig
 from ..models.transformer import decode_step, greedy_sample, prefill, \
@@ -150,7 +149,7 @@ def build_train_step(cfg: ModelConfig, bf16_grads: bool = False,
                 def pod_leg(g, e):
                     return compress_allreduce_pods(g, e, axis="pod")
 
-                grads, new_ef = shard_map(
+                grads, new_ef = jax.shard_map(
                     pod_leg, mesh=mesh, in_specs=(specs, specs),
                     out_specs=(specs, specs), check_vma=False,
                     axis_names={"pod"})(grads, ef)
@@ -214,7 +213,7 @@ def _coherence_prologue(mode: str, entries, sharers, owner, mut_t, mut_i,
                                             axis_name="pod")
         return local[None], sharers
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("pod"), P(), P(), P("pod"), P("pod"), P("pod"),
                   P("pod"), P("pod")),
@@ -294,9 +293,8 @@ def build_cell(arch: str, shape: ShapeSpec, mesh: Mesh,
                     lambda l: jax.ShapeDtypeStruct(l.shape, jnp.float32),
                     params_shapes)
                 args = args + (_shaped(ef_shapes, p_shards),)
-            step = functools.partial(_train_with_rules, cfg, rules,
-                                     opts.bf16_grads, opts.remat,
-                                     opts.compress_pod_grads)
+            step = with_rules(rules, build_train_step(
+                cfg, opts.bf16_grads, opts.remat, opts.compress_pod_grads))
             return CellSpec(arch, shape, cfg, step, args, rules,
                             donate=(0, 1))
 
@@ -307,7 +305,7 @@ def build_cell(arch: str, shape: ShapeSpec, mesh: Mesh,
         state_shapes = jax.eval_shape(
             lambda: init_decode_state(cfg, gb, n_frames, mb, enc_len=enc_len,
                                       n_pools=n_pools))
-        state = _shaped(state_shapes, _state_shardings(
+        state = _shaped(state_shapes, state_shardings(
             cfg, state_shapes, mesh, rules, sp=sp))
 
         if shape.step == "prefill":
@@ -327,7 +325,7 @@ def build_cell(arch: str, shape: ShapeSpec, mesh: Mesh,
                                              sharding=_named(mesh, batch_ax)),
                         jax.ShapeDtypeStruct((gb, mb), jnp.int32,
                                              sharding=_named(mesh, batch_ax)))
-            step = functools.partial(_prefill_with_rules, cfg, rules)
+            step = with_rules(rules, build_prefill_step(cfg))
             return CellSpec(arch, shape, cfg, step, args, rules, donate=(1,))
 
         # decode: tokens [gb], block tables [gb, mb]
@@ -359,13 +357,15 @@ def build_cell(arch: str, shape: ShapeSpec, mesh: Mesh,
                 jax.ShapeDtypeStruct((n_pods, miss_budget), i32,
                                      sharding=pod_sh),
             )
-        step = functools.partial(_serve_with_rules, cfg, rules, sp,
-                                 opts.decode_kernel, opts.coherence)
+        step = with_rules(rules, build_serve_step(
+            cfg, sp=sp, kernel=opts.decode_kernel, coherence=opts.coherence))
         return CellSpec(arch, shape, cfg, step, args, rules, donate=(1,))
 
 
-def _state_shardings(cfg: ModelConfig, state_shapes, mesh: Mesh,
-                     rules: ShardingRules, sp: bool) -> PyTree:
+def state_shardings(cfg: ModelConfig, state_shapes, mesh: Mesh,
+                    rules: ShardingRules, sp: bool) -> PyTree:
+    """NamedShardings for a decode state: the KV pool split over the
+    'blocks' axes, per-sequence state over 'batch'."""
     blocks_ax = rules.lookup("blocks")
     batch_ax = rules.lookup("batch") if not sp else None
     kv_ax = None if sp else rules.lookup("kv_heads")
@@ -396,22 +396,10 @@ def _state_shardings(cfg: ModelConfig, state_shapes, mesh: Mesh,
     return jax.tree_util.tree_map_with_path(shard_cache, state_shapes)
 
 
-# step closures carrying rules into trace time ------------------------------
-def _train_with_rules(cfg, rules, bf16_grads, remat, compress, params, opt,
-                      batch, ef=None):
-    with use_rules(rules):
-        step = build_train_step(cfg, bf16_grads, remat, compress)
-        if ef is not None:
-            return step(params, opt, batch, ef)
-        return step(params, opt, batch)
-
-
-def _prefill_with_rules(cfg, rules, *args):
-    with use_rules(rules):
-        return build_prefill_step(cfg)(*args)
-
-
-def _serve_with_rules(cfg, rules, sp, kernel, coherence, *args):
-    with use_rules(rules):
-        return build_serve_step(cfg, sp=sp, kernel=kernel,
-                                coherence=coherence)(*args)
+def with_rules(rules: ShardingRules, step: Callable) -> Callable:
+    """``step`` traced under ``rules``: the logical->mesh axis table that
+    the model's sharding constraints and shard_map regions read."""
+    def run(*args):
+        with use_rules(rules):
+            return step(*args)
+    return run

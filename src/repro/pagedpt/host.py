@@ -12,7 +12,7 @@ inside the jitted step.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -64,8 +64,9 @@ class HostBlockManager:
         self._table_seq_owner: Dict[int, int] = {}
         self._next_free_slot: Dict[int, int] = {}
         self.counters = HostCounters()
-        # outbound device buffers (drained once per step)
-        self._pending_mut: List[Tuple[int, int, int]] = []
+        # outbound device buffers (drained once per step); a mutation is
+        # (table, slot, value, pod that owned the table when it changed)
+        self._pending_mut: List[Tuple[int, int, int, int]] = []
         self._pending_miss: Dict[int, List[int]] = {p: [] for p in range(spec.n_pods)}
 
     # ------------------------------------------------------------ allocation
@@ -98,7 +99,8 @@ class HostBlockManager:
             if self.mode is CoherenceMode.EAGER:
                 self.present[:, tid, slot] = True
                 self.counters.coherence_bytes += 4 * (self.spec.n_pods - 1)
-            self._pending_mut.append((tid, slot, int(self.canonical[tid, slot])))
+            self._pending_mut.append((tid, slot, int(self.canonical[tid, slot]),
+                                      seq.pod))
             seq.logical_blocks.append(logical)
             new.append(logical)
             self.counters.mutations += 1
@@ -134,7 +136,7 @@ class HostBlockManager:
             self.free_frames.append(frame)
             self.canonical[tid, slot] = -1
             self.present[:, tid, slot] = False
-            self._pending_mut.append((tid, slot, -1))
+            self._pending_mut.append((tid, slot, -1, int(self.owner[tid])))
             self.counters.mutations += 1
         self._invalidate(touched)
         for tid in touched:
@@ -157,7 +159,8 @@ class HostBlockManager:
             tid, slot = divmod(logical, epb)
             frame = int(self.canonical[tid, slot]) & ((1 << 28) - 1)
             self.canonical[tid, slot] = _pack(frame, perms)
-            self._pending_mut.append((tid, slot, int(self.canonical[tid, slot])))
+            self._pending_mut.append((tid, slot, int(self.canonical[tid, slot]),
+                                      int(self.owner[tid])))
             self.counters.mutations += 1
             touched.add(tid)
         self._invalidate(sorted(touched))
@@ -213,27 +216,32 @@ class HostBlockManager:
             self.counters.coherence_bytes += 8
 
     # ------------------------------------------------------------ device I/O
-    def drain_mutation_buffer(self, budget: Optional[int] = None
-                              ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        budget = budget or self.spec.mutation_budget
+    def drain_pod_buffers(self) -> Tuple[np.ndarray, ...]:
+        """One step's coherence inputs, one row per pod, in the order
+        ``repro.launch.specs.build_serve_step`` takes them over the 'pod'
+        axis: ``mut_t, mut_i, mut_v, mut_ok`` [n_pods, mutation_budget]
+        (row p holds the mutations of the tables pod p owns; the other
+        slots hold table 0, slot 0, value -1, not ok) and ``miss``
+        [n_pods, miss_budget] (the logical blocks pod p missed, -1 fill).
+        Up to ``mutation_budget`` mutations and ``miss_budget`` misses per
+        pod are drained; the rest wait for the next step."""
+        n_pods = self.spec.n_pods
+        budget = self.spec.mutation_budget
         take, self._pending_mut = (self._pending_mut[:budget],
                                    self._pending_mut[budget:])
-        tables = np.full(budget, 0, dtype=np.int32)
-        idx = np.full(budget, 0, dtype=np.int32)
-        val = np.full(budget, -1, dtype=np.int32)
-        valid = np.zeros(budget, dtype=bool)
-        for i, (t, s, v) in enumerate(take):
-            tables[i], idx[i], val[i], valid[i] = t, s, v, True
-        return tables, idx, val, valid
-
-    def drain_miss_buffer(self, pod: int, budget: Optional[int] = None
-                          ) -> np.ndarray:
-        budget = budget or self.spec.miss_budget
-        take = self._pending_miss[pod][:budget]
-        self._pending_miss[pod] = self._pending_miss[pod][budget:]
-        out = np.full(budget, -1, dtype=np.int32)
-        out[:len(take)] = take
-        return out
+        mut_t = np.zeros((n_pods, budget), dtype=np.int32)
+        mut_i = np.zeros((n_pods, budget), dtype=np.int32)
+        mut_v = np.full((n_pods, budget), -1, dtype=np.int32)
+        mut_ok = np.zeros((n_pods, budget), dtype=bool)
+        for i, (t, s, v, pod) in enumerate(take):
+            mut_t[pod, i], mut_i[pod, i], mut_v[pod, i] = t, s, v
+            mut_ok[pod, i] = True
+        miss = np.full((n_pods, self.spec.miss_budget), -1, dtype=np.int32)
+        for pod in range(n_pods):
+            got = self._pending_miss[pod][:self.spec.miss_budget]
+            self._pending_miss[pod] = self._pending_miss[pod][len(got):]
+            miss[pod, :len(got)] = got
+        return mut_t, mut_i, mut_v, mut_ok, miss
 
     # ------------------------------------------------------------ validation
     def check_invariants(self) -> None:

@@ -1,20 +1,8 @@
 """Test configuration.  NOTE: no XLA_FLAGS here — smoke tests must see the
-real single CPU device; only launch/dryrun.py forces 512 host devices.
-
-Besides the path setup, this hosts the capability gate for the jax serving
-stack.  The stack needs shard_map / an active-mesh context / set_mesh; on
-old CPU-only wheels those are provided by ``repro.jaxcompat`` (the
-``jax.experimental.shard_map`` + ``Mesh``-context fallback), so the gate
-probes the *compat layer*, not the bare ``jax`` namespace — the serving
-tests run for real on 0.4.x wheels instead of skipping.  Tests only skip
-(with the missing capability named) on environments where even the
-fallback is absent, so tier-1 stays green-or-skip, never red, while every
-simulator/core test still runs everywhere.
-"""
+real single CPU device; multi-device tests force host devices in a
+subprocess of their own."""
 import os
 import sys
-
-import pytest
 
 # src/ for the repro package; repo root so `benchmarks` (the harness the
 # bench smoke test drives) is importable regardless of invocation cwd.
@@ -36,82 +24,3 @@ try:
                                               "default"))
 except ImportError:        # hypothesis extra not installed: seeded suites
     pass                   # still provide full coverage
-
-
-def _probe_capabilities():
-    """Which optional stacks does this environment actually provide?"""
-    caps = {}
-    try:
-        import jax  # noqa: F401
-        caps["jax"] = True
-    except Exception:
-        caps["jax"] = False
-    if caps["jax"]:
-        import jax
-        try:
-            import jax.experimental.pallas  # noqa: F401
-            caps["pallas"] = True
-        except Exception:
-            caps["pallas"] = False
-        # the serving/kvcache stack routes shard_map and the launch/elastic
-        # stack routes set_mesh through repro.jaxcompat (native or
-        # jax.experimental.shard_map / Mesh-context fallback on 0.4.x
-        # wheels); the compat layer itself reports what it can back.
-        try:
-            from repro.jaxcompat import available_capabilities
-            caps.update(available_capabilities())
-        except Exception:
-            caps["shard_map"] = caps["set_mesh"] = caps["jit"] = False
-    else:
-        caps["pallas"] = caps["shard_map"] = caps["set_mesh"] = False
-        caps["jit"] = False
-    return caps
-
-
-#: (file, test-name-or-None-for-whole-module, required capabilities).
-#: `test_decode_matches_forward` needs the paged-KV gather (shard_map) for
-#: every attention architecture; the purely recurrent configs decode
-#: without it and keep running.
-_RECURRENT_ARCHS = ("mamba2_370m", "recurrentgemma_2b")
-_REQUIREMENTS = [
-    ("test_kernels.py", None, ("jax", "pallas")),
-    ("test_models.py", None, ("jax",)),
-    ("test_models.py", "test_decode_matches_forward", ("shard_map",)),
-    ("test_models.py", "test_whisper_decode_matches_forward", ("shard_map",)),
-    ("test_runtime.py", "test_serving_modes_agree_and_filter", ("shard_map",)),
-    ("test_serve_driver.py", "test_serve_partial_final_wave_and_pod_fetches",
-     ("shard_map",)),
-    ("test_serve_driver.py", "test_serve_warms_jit_before_timer",
-     ("shard_map",)),
-    ("test_system.py", "test_end_to_end_serving_generates_same_tokens_"
-                       "under_all_policies", ("shard_map",)),
-    ("test_distributed.py", "test_small_mesh_train_and_serve_steps",
-     ("set_mesh",)),
-    ("test_distributed.py", "test_dryrun_cell_small_mesh", ("set_mesh",)),
-    ("test_distributed.py", "test_multi_pod_serve_cell", ("set_mesh",)),
-    ("test_elastic.py", "test_elastic_remesh_restore", ("set_mesh",)),
-    ("test_trace_differential.py", "test_fifo_miss_jit_matches_numpy",
-     ("jit",)),
-]
-
-
-def pytest_collection_modifyitems(config, items):
-    caps = _probe_capabilities()
-    if all(caps.values()):
-        return
-    for item in items:
-        fname = os.path.basename(str(item.fspath))
-        base = item.name.split("[")[0]
-        param = item.name[len(base):].strip("[]")
-        for req_file, req_test, needed in _REQUIREMENTS:
-            if fname != req_file or (req_test is not None and
-                                     base != req_test):
-                continue
-            if (req_test == "test_decode_matches_forward"
-                    and param in _RECURRENT_ARCHS):
-                continue  # recurrent decode has no paged-KV gather
-            missing = [c for c in needed if not caps[c]]
-            if missing:
-                item.add_marker(pytest.mark.skip(
-                    reason="jax capability unavailable in this "
-                           f"environment: {', '.join(missing)}"))

@@ -72,18 +72,18 @@ def test_small_mesh_train_and_serve_steps():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke_config
         from repro.distributed.sharding import ShardingRules, use_rules
-        from repro.jaxcompat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.specs import param_shardings, build_train_step
         from repro.models import init_params
         from repro.optim import adamw_init
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke_config("yi_6b")
         rules = ShardingRules(rules=(("batch", "data"), ("heads", "model"),
                                      ("ff", "model"), ("vocab", "model"),
                                      ("kv_heads", None), ("experts", "model"),
                                      ("blocks", "data"), ("head_dim", None),
                                      ("seq", None), ("embed", None)))
-        with use_rules(rules), set_mesh(mesh):
+        with use_rules(rules), jax.set_mesh(mesh):
             params = init_params(cfg, jax.random.PRNGKey(0))
             shards = param_shardings(params, mesh)
             params = jax.tree.map(jax.device_put, params, shards)
@@ -104,12 +104,12 @@ def test_dryrun_cell_small_mesh():
     """The dry-run machinery works end to end on a small forced mesh."""
     out = run_in_subprocess("""
         import jax
-        from repro.jaxcompat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.specs import build_cell
         from repro.configs import SHAPES
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cell = build_cell("yi_6b", SHAPES["train_4k"], mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(cell.step_fn,
                                donate_argnums=cell.donate).lower(
                 *cell.args).compile()
@@ -121,15 +121,39 @@ def test_dryrun_cell_small_mesh():
 def test_multi_pod_serve_cell():
     out = run_in_subprocess("""
         import jax
-        from repro.jaxcompat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.specs import build_cell
         from repro.configs import SHAPES
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cell = build_cell("yi_6b", SHAPES["decode_32k"], mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(cell.step_fn,
                                donate_argnums=cell.donate).lower(
                 *cell.args).compile()
         print("compiled-ok")
     """)
     assert "compiled-ok" in out
+
+
+def test_pod_mesh_coherence_step_matches_one_device():
+    """chip_smoke.py's four-chip phase at smoke size on 4 host devices:
+    eager and numaPTE coherence steps on a (pod=4) mesh with the KV pool
+    split per pod sample exactly the tokens of one device without the pod
+    axis, each pod's pool lies on its own device and holds exactly the
+    one device's KV, and every pod replica agrees with the host's
+    canonical table."""
+    out = run_in_subprocess(f"""
+        import sys
+        sys.path.insert(0, {str(ROOT)!r})
+        import jax
+        from chip_smoke import pod_mesh_phase
+        from repro.configs import get_smoke_config
+        r = pod_mesh_phase(get_smoke_config("qwen3_14b"), jax.devices()[:4],
+                           batch=8, prompt_len=40, steps=3)
+        assert r["n"] == 4 * 8
+        assert r["exact"] == {{"eager": 32, "numapte": 32}}, r["exact"]
+        assert r["kv_gap"] == {{m: [0.0] * 2 for m in ("eager", "numapte")}}, \\
+            r["kv_gap"]
+        print("pod-mesh-ok", sorted(r["tokens"]))
+    """)
+    assert "pod-mesh-ok ['eager', 'numapte', 'one_device']" in out

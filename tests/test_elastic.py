@@ -36,7 +36,7 @@ def test_elastic_remesh_restore(tmp_path):
         from repro.checkpoint import CheckpointManager
         from repro.data import SyntheticLMDataset
         from repro.distributed.sharding import ShardingRules, use_rules
-        from repro.jaxcompat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.specs import build_train_step, param_shardings
         from repro.models import init_params
         from repro.optim import adamw_init
@@ -52,7 +52,7 @@ def test_elastic_remesh_restore(tmp_path):
 
         def steps(mesh, params, opt, start, n):
             losses = []
-            with use_rules(rules), set_mesh(mesh):
+            with use_rules(rules), jax.set_mesh(mesh):
                 shards = param_shardings(params, mesh)
                 params = jax.tree.map(jax.device_put, params, shards)
                 opt = jax.tree.map(jax.device_put, opt,
@@ -69,7 +69,7 @@ def test_elastic_remesh_restore(tmp_path):
             return params, opt, losses
 
         # phase 1: full fleet (2 data x 4 model)
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_a = make_mesh((2, 4), ("data", "model"))
         params = init_params(cfg, jax.random.PRNGKey(0))
         opt = adamw_init(params)
         params, opt, l1 = steps(mesh_a, params, opt, 0, 6)
@@ -79,10 +79,10 @@ def test_elastic_remesh_restore(tmp_path):
         _, _, ref = steps(mesh_a, params, opt, 6, 4)
 
         # phase 2: half the fleet died -> (2 data x 2 model) mesh
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_b = make_mesh((2, 2), ("data", "model"))
         like = {{"params": init_params(cfg, jax.random.PRNGKey(0)),
                 "opt": adamw_init(init_params(cfg, jax.random.PRNGKey(0)))}}
-        with use_rules(rules), set_mesh(mesh_b):
+        with use_rules(rules), jax.set_mesh(mesh_b):
             shards = {{"params": param_shardings(like["params"], mesh_b),
                       "opt": None}}
             state = ckpt.restore(6, like)

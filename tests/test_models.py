@@ -107,3 +107,18 @@ def test_shape_cells_cover_assignment():
     assert len(cells) == 33
     assert ("mamba2_370m", "long_500k") in cells
     assert ("qwen3_14b", "long_500k") not in cells
+
+
+def test_one_chip_config_cuts_only_depth():
+    """The one-chip qwen3_14b keeps every published width and differs from
+    the full config only in depth (and bf16 weights, as published)."""
+    import dataclasses
+
+    from repro.configs import get_one_chip_config
+
+    full, chip = get_config("qwen3_14b"), get_one_chip_config("qwen3_14b")
+    assert chip.n_layers == 8 and chip.param_dtype == jnp.bfloat16
+    assert dataclasses.replace(chip, name=full.name, n_layers=full.n_layers,
+                               param_dtype=full.param_dtype) == full
+    with pytest.raises(ValueError):
+        get_one_chip_config("yi_6b")

@@ -50,6 +50,26 @@ def test_sharer_filter_scopes_invalidations():
     assert mgr_n.counters.invalidations_filtered == SPEC.n_pods - 1
 
 
+def test_drain_pod_buffers_rows_follow_the_owning_pod():
+    """Pod p's row carries the mutations of the tables p owned when they
+    changed, a free's invalidations included, and the misses p recorded."""
+    mgr = HostBlockManager(SPEC, CoherenceMode.NUMAPTE)
+    blocks = mgr.alloc_sequence(0, 2, pod=1)
+    mgr.alloc_sequence(1, 1, pod=3)
+    mgr.record_access(2, blocks[0])
+    mgr.free_sequence(0)
+    mut_t, mut_i, mut_v, mut_ok, miss = mgr.drain_pod_buffers()
+    assert mut_t.shape == mut_v.shape == (SPEC.n_pods, SPEC.mutation_budget)
+    assert mut_ok.sum(1).tolist() == [0, 4, 0, 1]
+    assert (mut_v[1][mut_ok[1]][:2] >= 0).all()
+    assert (mut_v[1][mut_ok[1]][2:] == -1).all()
+    assert (mut_v[~mut_ok] == -1).all() and (mut_t[~mut_ok] == 0).all()
+    assert miss.shape == (SPEC.n_pods, SPEC.miss_budget)
+    assert miss[2, 0] == blocks[0] and (miss[2, 1:] == -1).all()
+    assert (miss[[0, 1, 3]] == -1).all()
+    assert not mgr.drain_pod_buffers()[3].any()
+
+
 op = st.tuples(st.sampled_from(["alloc", "extend", "access", "protect",
                                 "free"]),
                st.integers(0, 5), st.integers(0, 3), st.integers(1, 8))

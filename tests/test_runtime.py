@@ -114,6 +114,7 @@ def test_serving_modes_agree_and_filter():
     eager = serve("yi_6b", n_requests=6, prompt_len=24, gen_len=6, batch=3,
                   n_pods=4, mode="eager", verbose=False)
     assert base["tokens"] == eager["tokens"]
+    np.testing.assert_array_equal(base["generated"], eager["generated"])
     assert base["invalidations_filtered"] > 0
     assert eager["invalidations_filtered"] == 0
     assert base["invalidations_sent"] < eager["invalidations_sent"]

@@ -20,8 +20,10 @@ def test_end_to_end_serving_generates_same_tokens_under_all_policies():
         outs[mode] = serve("gemma3_4b", n_requests=4, prompt_len=20,
                            gen_len=5, batch=2, n_pods=2, mode=mode,
                            verbose=False)
-    toks = {m: o["tokens"] for m, o in outs.items()}
-    assert len(set(toks.values())) == 1
+    assert len({o["tokens"] for o in outs.values()}) == 1
+    for mode in ("eager", "numapte"):
+        np.testing.assert_array_equal(outs[mode]["generated"],
+                                      outs["local"]["generated"])
 
 
 def test_numapte_scales_with_sockets():
