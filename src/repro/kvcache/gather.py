@@ -10,6 +10,10 @@ reads, instead of the all-gather a flat sharded pool would force.
 
 ``update_gather_pooled`` runs under shard_map over ('data',) nested in the
 jitted step; head_dim stays sharded over 'model' outside the map.
+
+The device trace names this module's work by scope: ``kv_gather`` (the
+read of a step's live blocks), ``kv_commit`` (the decode step's token
+writes) and ``kv_scatter`` (the prefill's writes).
 """
 from __future__ import annotations
 
@@ -29,6 +33,7 @@ def _on_mesh(mesh, axis) -> bool:
     return mesh is not None and all(n in mesh.axis_names for n in names)
 
 
+@jax.named_scope("kv_gather")
 def update_gather_plain(k_slabs: jax.Array, v_slabs: jax.Array,
                         k_new: jax.Array, v_new: jax.Array,
                         phys_blocks: jax.Array, positions: jax.Array,
@@ -66,6 +71,7 @@ def update_gather_plain(k_slabs: jax.Array, v_slabs: jax.Array,
         return k_slabs, v_slabs, k_slabs[gather], v_slabs[gather]
 
 
+@jax.named_scope("kv_gather")
 def gather_readonly(k_stack: jax.Array, v_stack: jax.Array,
                     layer_idx: jax.Array, phys_blocks: jax.Array,
                     fused_scope: bool = False
@@ -154,6 +160,7 @@ def _commit_plain(k_stack, v_stack, k_new, v_new, frame, slot, valid=None):
     return k_stack, v_stack
 
 
+@jax.named_scope("kv_commit")
 def commit_token_writes(k_stack: jax.Array, v_stack: jax.Array,
                         k_new: jax.Array, v_new: jax.Array,
                         phys_blocks: jax.Array, positions: jax.Array,
@@ -204,6 +211,7 @@ def commit_token_writes(k_stack: jax.Array, v_stack: jax.Array,
     return f(k_stack, v_stack, k_new, v_new, frame, slot, valid)
 
 
+@jax.named_scope("kv_gather")
 def update_gather_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
                          k_new: jax.Array, v_new: jax.Array,
                          phys_blocks: jax.Array, positions: jax.Array,
@@ -374,6 +382,7 @@ def decode_attention_sp(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
     return f(q, k_slabs, v_slabs, phys_blocks, positions, seq_lens)
 
 
+@jax.named_scope("kv_scatter")
 def scatter_prefill_plain(k_slabs: jax.Array, v_slabs: jax.Array,
                           k: jax.Array, v: jax.Array,
                           phys_blocks: jax.Array, positions: jax.Array,
@@ -396,6 +405,7 @@ def scatter_prefill_plain(k_slabs: jax.Array, v_slabs: jax.Array,
     return k_slabs, v_slabs
 
 
+@jax.named_scope("kv_scatter")
 def scatter_prefill_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
                            k: jax.Array, v: jax.Array,
                            phys_blocks: jax.Array, positions: jax.Array,

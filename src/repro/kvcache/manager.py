@@ -7,24 +7,21 @@ per-pod replicas, sharer masks and invalidation filtering.  Every decode
 step translates the logical tables to physical tables (the page walk; on
 device via ``repro.kernels.pte_gather`` or ``repro.pagedpt.lookup_blocks``)
 and hands the physical tables to the paged-attention kernel.
+
+Each call that changes or walks the tables is a profiler span on the
+device trace's clock (``jax.profiler.TraceAnnotation``): ``kv.start`` and
+``kv.finish`` per sequence, ``kv.extend`` when a block is added, ``kv.walk``
+per ``physical_tables``.  Outside a trace a span costs about a microsecond.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..pagedpt import BlockTableSpec, HostBlockManager
 from ..pagedpt.blocktable import CoherenceMode
-
-
-@dataclasses.dataclass
-class ServingStats:
-    steps: int = 0
-    tokens: int = 0
-    seqs_started: int = 0
-    seqs_finished: int = 0
 
 
 class PagedKVManager:
@@ -50,26 +47,27 @@ class PagedKVManager:
         #: the scheduler's pod: it walks every row's tail block to commit
         #: appended tokens (see ``physical_tables``)
         self.driver_pod = 0
-        self.stats = ServingStats()
 
     # ------------------------------------------------------------- lifecycle
     def start_sequence(self, seq_id: int, prompt_len: int, pod: int = 0
                        ) -> None:
         n_blocks = max(1, -(-prompt_len // self.block_tokens))
-        self.host.alloc_sequence(seq_id, n_blocks, pod)
+        with TraceAnnotation("kv.start", seq=seq_id, pod=pod):
+            self.host.alloc_sequence(seq_id, n_blocks, pod)
         self._seq_pod[seq_id] = pod
-        self.stats.seqs_started += 1
 
     def maybe_extend(self, seq_id: int, new_len: int) -> None:
         have = len(self.host.seqs[seq_id].logical_blocks)
         need = -(-new_len // self.block_tokens)
         if need > have:
-            self.host.extend_sequence(seq_id, need - have)
+            with TraceAnnotation("kv.extend", seq=seq_id,
+                                 pod=self._seq_pod[seq_id]):
+                self.host.extend_sequence(seq_id, need - have)
 
     def finish_sequence(self, seq_id: int) -> None:
-        self.host.free_sequence(seq_id)
-        self._seq_pod.pop(seq_id, None)
-        self.stats.seqs_finished += 1
+        pod = self._seq_pod.pop(seq_id, None)
+        with TraceAnnotation("kv.finish", seq=seq_id, pod=pod):
+            self.host.free_sequence(seq_id)
 
     # ------------------------------------------------------------ tables
     def logical_tables(self, seq_ids: List[int]) -> np.ndarray:
@@ -98,33 +96,30 @@ class PagedKVManager:
         under NUMAPTE once sequences are homed off pod 0.  An explicit
         ``pod`` keeps the legacy single-pod walk.  Misses trigger the
         numaPTE on-demand fetch protocol; negative seq ids (padding rows)
-        are skipped entirely."""
-        logical = self.logical_tables(seq_ids)
-        epb = self.spec.entries_per_table
-        out = np.full_like(logical, -1)
-        for r, sid in enumerate(seq_ids):
-            if sid < 0:
-                continue
-            walk_pod = self._seq_pod[sid] if pod is None else pod
-            tail_lb = -1
-            for c in range(logical.shape[1]):
-                lb = int(logical[r, c])
-                if lb < 0:
+        are skipped entirely.  Every valid entry translated counts in
+        ``HostCounters.entries_walked``."""
+        with TraceAnnotation("kv.walk", rows=len(seq_ids), record=record):
+            logical = self.logical_tables(seq_ids)
+            epb = self.spec.entries_per_table
+            # padding rows are all -1: they add nothing
+            self.host.counters.entries_walked += int((logical >= 0).sum())
+            out = np.full_like(logical, -1)
+            for r, sid in enumerate(seq_ids):
+                if sid < 0:
                     continue
-                if record:
-                    self.host.record_access(walk_pod, lb)
-                tid, slot = divmod(lb, epb)
-                raw = int(self.host.canonical[tid, slot])
-                out[r, c] = raw & ((1 << 28) - 1) if raw >= 0 else -1
-                tail_lb = lb
-            if (pod is None and record and tail_lb >= 0
-                    and walk_pod != self.driver_pod):
-                self.host.record_access(self.driver_pod, tail_lb)
-        return out
-
-    # ------------------------------------------------------------ accounting
-    def utilization(self) -> float:
-        return 1.0 - len(self.host.free_frames) / self.n_frames
-
-    def footprint_pages(self) -> int:
-        return self.host.footprint_table_pages()
+                walk_pod = self._seq_pod[sid] if pod is None else pod
+                tail_lb = -1
+                for c in range(logical.shape[1]):
+                    lb = int(logical[r, c])
+                    if lb < 0:
+                        continue
+                    if record:
+                        self.host.record_access(walk_pod, lb)
+                    tid, slot = divmod(lb, epb)
+                    raw = int(self.host.canonical[tid, slot])
+                    out[r, c] = raw & ((1 << 28) - 1) if raw >= 0 else -1
+                    tail_lb = lb
+                if (pod is None and record and tail_lb >= 0
+                        and walk_pod != self.driver_pod):
+                    self.host.record_access(self.driver_pod, tail_lb)
+            return out

@@ -132,7 +132,8 @@ def serve(arch: str, *, size: str = "smoke", n_requests: int = 16,
         "invalidations_filtered": c.invalidations_filtered,
         "coherence_bytes": c.coherence_bytes,
         "fetches": c.fetches, "prefetched": c.prefetched,
-        "table_pages": kv.footprint_pages(),
+        # the peak: every table page is freed by the time the run ends
+        "table_pages": c.table_pages_peak,
         "n_layers": cfg.n_layers, "param_bytes": param_bytes,
         "prefill_compile_s": prefill_compile_s,
         "decode_compile_s": decode_compile_s,

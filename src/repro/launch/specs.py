@@ -189,6 +189,7 @@ def build_serve_step(cfg: ModelConfig, sp: bool = False,
     return step
 
 
+@jax.named_scope("coherence")
 def _coherence_prologue(mode: str, entries, sharers, owner, mut_t, mut_i,
                         mut_v, mut_ok, miss):
     """Per-step block-table coherence over the 'pod' axis — the paper's

@@ -50,6 +50,18 @@ def _project_qkv(cfg: ModelConfig, p: Dict[str, jax.Array], xq: jax.Array,
     return q, k, v
 
 
+@jax.named_scope("attn_qkv")
+def _qkv_decode(cfg: ModelConfig, p: Dict[str, jax.Array], x: jax.Array,
+                positions: jax.Array, rope_theta: float
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One decode token's q, k, v [B,1,*,hd], RoPE'd at ``positions``."""
+    q, k, v = _project_qkv(cfg, p, x, x)
+    if cfg.use_rope:
+        q = apply_rope(q, positions[:, None], rope_theta)
+        k = apply_rope(k, positions[:, None], rope_theta)
+    return q, k, v
+
+
 def _gqa_scores(cfg: ModelConfig, q: jax.Array, k: jax.Array) -> jax.Array:
     """q [B,Sq,H,hd], k [B,Sk,K,hd] -> scores [B,K,G,Sq,Sk]."""
     B, Sq, H, hd = q.shape
@@ -91,19 +103,20 @@ def attn_forward(cfg: ModelConfig, p: Dict[str, jax.Array], x: jax.Array,
     """
     cross = kv_x is not None
     xkv = kv_x if cross else x
-    q, k, v = _project_qkv(cfg, p, x, xkv)
-    q = constrain(q, "batch", "seq", "heads", None)
-    k = constrain(k, "batch", "seq", "kv_heads", None)
-    v = constrain(v, "batch", "seq", "kv_heads", None)
-    if cfg.use_rope:
-        q = apply_rope(q, positions, rope_theta)
-        if not cross:
-            k = apply_rope(k, kv_positions if kv_positions is not None
-                           else positions, rope_theta)
+    with jax.named_scope("attn_qkv"):
+        q, k, v = _project_qkv(cfg, p, x, xkv)
+        q = constrain(q, "batch", "seq", "heads", None)
+        k = constrain(k, "batch", "seq", "kv_heads", None)
+        v = constrain(v, "batch", "seq", "kv_heads", None)
+        if cfg.use_rope:
+            q = apply_rope(q, positions, rope_theta)
+            if not cross:
+                k = apply_rope(k, kv_positions if kv_positions is not None
+                               else positions, rope_theta)
     # The scores/softmax core ships as the Pallas flash kernel on TPU
     # (repro.kernels.flash_attention); the named scope declares its
     # intermediates VMEM-resident for the dry-run byte accounting.
-    with jax.named_scope("vmem_attn"):
+    with jax.named_scope("attn"), jax.named_scope("vmem_attn"):
         scores = _gqa_scores(cfg, q, k)         # [B,K,G,Sq,Sk]
         q_pos = positions if positions.ndim == 2 else positions[None]
         k_pos = kv_positions if kv_positions is not None else positions
@@ -140,16 +153,13 @@ def attn_decode_paged_ro(cfg: ModelConfig, p: Dict[str, jax.Array],
     hd = cfg.resolved_head_dim
     K = cfg.n_kv_heads
     bt = k_stack.shape[-3]
-    q, k_new, v_new = _project_qkv(cfg, p, x, x)
-    if cfg.use_rope:
-        q = apply_rope(q, positions[:, None], rope_theta)
-        k_new = apply_rope(k_new, positions[:, None], rope_theta)
+    q, k_new, v_new = _qkv_decode(cfg, p, x, positions, rope_theta)
     k_all, v_all = gather_readonly(k_stack, v_stack, layer_idx, phys_blocks,
                                    fused_scope)
     nb = phys_blocks.shape[1]
     k_all = k_all.reshape(B, nb * bt, K, hd)
     v_all = v_all.reshape(B, nb * bt, K, hd)
-    with jax.named_scope("vmem_paged_attn"):
+    with jax.named_scope("attn"), jax.named_scope("vmem_paged_attn"):
         scores = _gqa_scores(cfg, q, k_all)           # [B,K,G,1,T]
         s_new = _gqa_scores(cfg, q, k_new)            # [B,K,G,1,1]
         t = jnp.arange(nb * bt)
@@ -198,10 +208,7 @@ def attn_decode_paged(cfg: ModelConfig, p: Dict[str, jax.Array],
     hd = cfg.resolved_head_dim
     K = cfg.n_kv_heads
     bt = kv[0].shape[-3]
-    q, k_new, v_new = _project_qkv(cfg, p, x, x)
-    if cfg.use_rope:
-        q = apply_rope(q, positions[:, None], rope_theta)
-        k_new = apply_rope(k_new, positions[:, None], rope_theta)
+    q, k_new, v_new = _qkv_decode(cfg, p, x, positions, rope_theta)
     if sp:
         # sequence-parallel long-context decode (flash-decoding combine)
         out, k_slabs, v_slabs = decode_attention_sp(
@@ -217,10 +224,12 @@ def attn_decode_paged(cfg: ModelConfig, p: Dict[str, jax.Array],
         k_slabs, v_slabs, _, _ = fn(kv[0], kv[1], k_new[:, 0], v_new[:, 0],
                                     phys_blocks, positions, bt)
         from ..kernels.paged_attention import ops as pa_ops
-        out = pa_ops.paged_attention(q[:, 0], k_slabs, v_slabs, phys_blocks,
-                                     seq_lens, window=window)
-        out = out.reshape(B, 1, cfg.n_heads * hd).astype(cfg.dtype)
-        out = out @ p["wo"].astype(cfg.dtype)
+        with jax.named_scope("attn"):
+            out = pa_ops.paged_attention(q[:, 0], k_slabs, v_slabs,
+                                         phys_blocks, seq_lens,
+                                         window=window)
+            out = out.reshape(B, 1, cfg.n_heads * hd).astype(cfg.dtype)
+            out = out @ p["wo"].astype(cfg.dtype)
         return constrain(out, "batch", None, None), (k_slabs, v_slabs)
 
     # kernel == "fused_ref": the whole update+gather+softmax region is the
@@ -239,7 +248,7 @@ def attn_decode_paged(cfg: ModelConfig, p: Dict[str, jax.Array],
         k_all = k_all.reshape(B, nb * bt, K, hd)
         v_all = v_all.reshape(B, nb * bt, K, hd)
         # scores/softmax ship as the Pallas paged-attention kernel on TPU
-        with jax.named_scope("vmem_paged_attn"):
+        with jax.named_scope("attn"), jax.named_scope("vmem_paged_attn"):
             scores = _gqa_scores(cfg, q, k_all)    # [B,K,G,1,T]
             t = jnp.arange(nb * bt)
             valid = (t[None, :] < seq_lens[:, None])
@@ -261,10 +270,7 @@ def attn_decode_ring(cfg: ModelConfig, p: Dict[str, jax.Array], x: jax.Array,
     B = x.shape[0]
     hd = cfg.resolved_head_dim
     K = cfg.n_kv_heads
-    q, k_new, v_new = _project_qkv(cfg, p, x, x)
-    if cfg.use_rope:
-        q = apply_rope(q, positions[:, None], rope_theta)
-        k_new = apply_rope(k_new, positions[:, None], rope_theta)
+    q, k_new, v_new = _qkv_decode(cfg, p, x, positions, rope_theta)
     slot = positions % window
     ring_k = jax.vmap(lambda r, s, val: r.at[s].set(val))(
         ring_k, slot, k_new[:, 0].astype(ring_k.dtype))

@@ -291,20 +291,19 @@ def decode_step(cfg: ModelConfig, params: PyTree, state: DecodeState,
                 ) -> Tuple[jax.Array, DecodeState]:
     """One token per sequence.  tokens: [B]; phys_blocks: [B, max_blocks]
     physical frame ids from the numaPTE block-table translation."""
-    B = tokens.shape[0]
     positions = state.seq_lens                       # position of new token
-    if cfg.family == "encdec":
-        x = params["dec_embedding"].astype(cfg.dtype)[tokens][:, None]
-        pos_emb = params["dec_pos"].astype(cfg.dtype)[
-            jnp.clip(positions, 0, cfg.max_decoder_len - 1)]
-        x = x + pos_emb[:, None]
-    else:
-        x = params["embedding"].astype(cfg.dtype)[tokens][:, None]
-        x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
+    with jax.named_scope("embed"):
+        if cfg.family == "encdec":
+            x = params["dec_embedding"].astype(cfg.dtype)[tokens][:, None]
+            pos_emb = params["dec_pos"].astype(cfg.dtype)[
+                jnp.clip(positions, 0, cfg.max_decoder_len - 1)]
+            x = x + pos_emb[:, None]
+        else:
+            x = params["embedding"].astype(cfg.dtype)[tokens][:, None]
+            x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
     groups = layer_groups(cfg)
     new_caches: List[Dict[str, jax.Array]] = []
     seq_lens = state.seq_lens + 1
-    gi = 0
     for g, gp, cache in zip(groups, params["groups"], state.caches):
         if g.kind == "enc_attn":
             new_caches.append(cache)
@@ -312,13 +311,13 @@ def decode_step(cfg: ModelConfig, params: PyTree, state: DecodeState,
         x, cache = _decode_group(cfg, g, gp, cache, x, positions,
                                  phys_blocks, seq_lens, kernel, sp)
         new_caches.append(cache)
-        gi += 1
-    x = apply_norm(cfg, x, params["final_norm"])
-    head = params.get(
-        "lm_head",
-        (params["dec_embedding"] if cfg.family == "encdec"
-         else params["embedding"]).T)
-    logits = (x @ head.astype(cfg.dtype))[:, 0]
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, x, params["final_norm"])
+        head = params.get(
+            "lm_head",
+            (params["dec_embedding"] if cfg.family == "encdec"
+             else params["embedding"]).T)
+        logits = (x @ head.astype(cfg.dtype))[:, 0]
     return logits, DecodeState(tuple(new_caches), seq_lens)
 
 
@@ -338,23 +337,20 @@ def _decode_group(cfg: ModelConfig, g: LayerGroup, gp: PyTree,
 
             def body(x, xs):
                 lp, li, *cross = xs
-                h = apply_norm(cfg, x, lp["norm1"])
+                with jax.named_scope("attn_qkv"):
+                    h = apply_norm(cfg, x, lp["norm1"])
                 a, kn, vn = attn_decode_paged_ro(
                     cfg, lp["attn"], h, positions, k_stack, v_stack, li,
                     phys_blocks, seq_lens, rope_theta=g.rope_theta,
                     fused_scope=(kernel == "fused_ref"))
-                x = x + a
+                with jax.named_scope("attn"):
+                    x = x + a
                 if cross:
                     ck, cv = cross
                     h = apply_norm(cfg, x, lp["norm_cross"])
                     a = _cross_decode(cfg, lp["cross"], h, ck, cv)
                     x = x + a
-                h = apply_norm(cfg, x, lp["norm2"])
-                if g.moe:
-                    f, _ = moe_forward(cfg, lp["moe"], h)
-                else:
-                    f = ffn_forward(cfg, lp["ffn"], h)
-                return x + f, (kn, vn)
+                return _ffn_block(cfg, g, lp, x), (kn, vn)
 
             L = jax.tree.leaves(gp)[0].shape[0]
             xs = (gp, jnp.arange(L))
@@ -369,22 +365,19 @@ def _decode_group(cfg: ModelConfig, g: LayerGroup, gp: PyTree,
 
         def body(x, xs):
             lp, ks, vs, *cross = xs
-            h = apply_norm(cfg, x, lp["norm1"])
+            with jax.named_scope("attn_qkv"):
+                h = apply_norm(cfg, x, lp["norm1"])
             a, (ks, vs) = attn_decode_paged(
                 cfg, lp["attn"], h, positions, (ks, vs), phys_blocks,
                 seq_lens, rope_theta=g.rope_theta, kernel=kernel, sp=sp)
-            x = x + a
+            with jax.named_scope("attn"):
+                x = x + a
             if cross:
                 ck, cv = cross
                 h = apply_norm(cfg, x, lp["norm_cross"])
                 a = _cross_decode(cfg, lp["cross"], h, ck, cv)
                 x = x + a
-            h = apply_norm(cfg, x, lp["norm2"])
-            if g.moe:
-                f, _ = moe_forward(cfg, lp["moe"], h)
-            else:
-                f = ffn_forward(cfg, lp["ffn"], h)
-            return x + f, (ks, vs)
+            return _ffn_block(cfg, g, lp, x), (ks, vs)
 
         xs = (gp, cache["k_slabs"], cache["v_slabs"])
         if g.kind == "dec_attn":
@@ -430,6 +423,18 @@ def _decode_group(cfg: ModelConfig, g: LayerGroup, gp: PyTree,
     raise ValueError(g.kind)
 
 
+@jax.named_scope("ffn")
+def _ffn_block(cfg: ModelConfig, g: LayerGroup, lp: PyTree,
+               x: jax.Array) -> jax.Array:
+    """Pre-norm FFN (or MoE) sublayer with its residual."""
+    h = apply_norm(cfg, x, lp["norm2"])
+    if g.moe:
+        f, _ = moe_forward(cfg, lp["moe"], h)
+    else:
+        f = ffn_forward(cfg, lp["ffn"], h)
+    return x + f
+
+
 def _cross_decode(cfg: ModelConfig, p: PyTree, x: jax.Array, ck: jax.Array,
                   cv: jax.Array) -> jax.Array:
     """Cross-attention decode against precomputed encoder KV [B,Se,K,hd]."""
@@ -451,9 +456,9 @@ def prefill(cfg: ModelConfig, params: PyTree, tokens: jax.Array,
     groups scatter their per-layer K/V through the block table.
     """
     B, S = tokens.shape
-    bt = cfg.kv_block_tokens
-    x = params["embedding"].astype(cfg.dtype)[tokens]
-    x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embedding"].astype(cfg.dtype)[tokens]
+        x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     groups = layer_groups(cfg)
     new_caches: List[Dict[str, jax.Array]] = []
@@ -461,9 +466,10 @@ def prefill(cfg: ModelConfig, params: PyTree, tokens: jax.Array,
         x, cache = _prefill_group(cfg, g, gp, cache, x, positions,
                                   phys_blocks)
         new_caches.append(cache)
-    x = apply_norm(cfg, x, params["final_norm"])
-    head = params.get("lm_head", params["embedding"].T)
-    logits = (x[:, -1] @ head.astype(cfg.dtype))
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, x, params["final_norm"])
+        head = params.get("lm_head", params["embedding"].T)
+        logits = (x[:, -1] @ head.astype(cfg.dtype))
     return logits, DecodeState(tuple(new_caches),
                                jnp.full((B,), S, jnp.int32))
 
@@ -482,23 +488,21 @@ def _prefill_group(cfg, g, gp, cache, x, positions, phys_blocks):
         def body(carry, xs):
             x = carry
             lp, ks, vs = xs
-            h = apply_norm(cfg, x, lp["norm1"])
+            with jax.named_scope("attn_qkv"):
+                h = apply_norm(cfg, x, lp["norm1"])
             a = attn_forward(cfg, lp["attn"], h, positions, window=None,
                              rope_theta=g.rope_theta)
             # scatter this layer's K/V into the paged slabs (pool-local)
-            q, k, v = _project_qkv(cfg, lp["attn"], h, h)
-            if cfg.use_rope:
-                k = apply_rope(k, positions, g.rope_theta)
+            with jax.named_scope("attn_qkv"):
+                q, k, v = _project_qkv(cfg, lp["attn"], h, h)
+                if cfg.use_rope:
+                    k = apply_rope(k, positions, g.rope_theta)
             scatter = (scatter_prefill_pooled if ks.ndim == 5
                        else scatter_prefill_plain)
             ks, vs = scatter(ks, vs, k, v, phys_blocks, positions, bt)
-            x = x + a
-            h = apply_norm(cfg, x, lp["norm2"])
-            if g.moe:
-                f, _ = moe_forward(cfg, lp["moe"], h)
-            else:
-                f = ffn_forward(cfg, lp["ffn"], h)
-            return x + f, (ks, vs)
+            with jax.named_scope("attn"):
+                x = x + a
+            return _ffn_block(cfg, g, lp, x), (ks, vs)
 
         x, (ks, vs) = jax.lax.scan(
             body, x, (gp, cache["k_slabs"], cache["v_slabs"]))
@@ -627,5 +631,8 @@ def prefill_encdec(cfg: ModelConfig, params: PyTree, enc_feats: jax.Array,
                                jnp.full((B,), Sd, jnp.int32))
 
 
+@jax.named_scope("lm_head")
 def greedy_sample(logits: jax.Array) -> jax.Array:
+    """Argmax over the vocabulary.  Named with the head that feeds it: XLA
+    fuses the head's matmul into the argmax."""
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)

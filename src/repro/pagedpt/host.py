@@ -7,7 +7,8 @@ read-only is mprotect.  Every mutation computes its exact invalidation scope
 from the sharer masks (invariant I2), so the counters this class keeps are
 the serving-level equivalents of the paper's shootdown counts, and the
 mutation/miss buffers it emits are consumed by ``repro.pagedpt.coherence``
-inside the jitted step.
+inside the jitted step.  A shootdown round (``pt.invalidate``) and a
+drain of the device buffers (``pt.drain``) are profiler spans.
 """
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ import dataclasses
 from typing import Dict, List, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .blocktable import (BlockTableSpec, CoherenceMode, PERM_RW, PERM_R,
                          pack_entry)
@@ -26,16 +28,21 @@ def _pack(frame: int, perms: int) -> int:
 
 @dataclasses.dataclass
 class HostCounters:
+    """Deterministic counts of the protocol's work (no times)."""
     allocs: int = 0
     frees: int = 0
     mutations: int = 0
     invalidations_sent: int = 0      # pod-invalidation messages issued
     invalidations_filtered: int = 0  # saved by the sharer filter
+    invalidation_rounds: int = 0     # shootdown rounds (one per mutation
+    #                                  batch: a free or a protect)
     fetches: int = 0                 # on-demand replica fills (misses)
     prefetched: int = 0
     translation_local: int = 0
     translation_miss: int = 0
     coherence_bytes: int = 0         # host-protocol bytes moved cross-pod
+    entries_walked: int = 0          # valid entries the page walk translated
+    table_pages_peak: int = 0        # most replicated table pages at once
 
 
 @dataclasses.dataclass
@@ -64,6 +71,9 @@ class HostBlockManager:
         self._table_seq_owner: Dict[int, int] = {}
         self._next_free_slot: Dict[int, int] = {}
         self.counters = HostCounters()
+        #: replicated table pages now (set sharer bits of owned tables),
+        #: kept where the bits change; ``footprint_table_pages`` rescans
+        self._table_pages = 0
         # outbound device buffers (drained once per step); a mutation is
         # (table, slot, value, pod that owned the table when it changed)
         self._pending_mut: List[Tuple[int, int, int, int]] = []
@@ -120,6 +130,7 @@ class HostBlockManager:
         self.sharers[tid] = np.uint32(1 << seq.pod)
         if self.mode is CoherenceMode.EAGER:
             self.sharers[tid] = np.uint32((1 << self.spec.n_pods) - 1)
+        self._add_table_pages(bin(int(self.sharers[tid])).count("1"))
         self._table_seq_owner[tid] = seq.seq_id
         self._next_free_slot[tid] = 0
         return tid
@@ -143,6 +154,7 @@ class HostBlockManager:
             if self._table_seq_owner.get(tid) == seq_id:
                 self.free_tables.append(tid)
                 self.owner[tid] = -1
+                self._add_table_pages(-bin(int(self.sharers[tid])).count("1"))
                 self.sharers[tid] = 0
                 del self._table_seq_owner[tid]
                 del self._next_free_slot[tid]
@@ -166,21 +178,30 @@ class HostBlockManager:
         self._invalidate(sorted(touched))
 
     def _invalidate(self, touched_tables: List[int]) -> None:
-        """Count invalidation messages: EAGER/LOCAL broadcast to every pod;
-        NUMAPTE sends only to pods in the sharer masks."""
-        n_pods = self.spec.n_pods
-        all_pods = set(range(n_pods))
-        scope: set = set()
-        for tid in touched_tables:
-            mask = int(self.sharers[tid])
-            scope |= {p for p in range(n_pods) if mask >> p & 1}
-        if self.mode is CoherenceMode.NUMAPTE:
-            targets = scope
-        else:
-            targets = all_pods
-        self.counters.invalidations_sent += len(targets)
-        self.counters.invalidations_filtered += len(all_pods) - len(targets)
-        self.counters.coherence_bytes += 12 * len(targets)
+        """One shootdown round.  Count its invalidation messages:
+        EAGER/LOCAL broadcast to every pod; NUMAPTE sends only to pods in
+        the sharer masks."""
+        with TraceAnnotation("pt.invalidate", tables=len(touched_tables)):
+            n_pods = self.spec.n_pods
+            all_pods = set(range(n_pods))
+            scope: set = set()
+            for tid in touched_tables:
+                mask = int(self.sharers[tid])
+                scope |= {p for p in range(n_pods) if mask >> p & 1}
+            if self.mode is CoherenceMode.NUMAPTE:
+                targets = scope
+            else:
+                targets = all_pods
+            c = self.counters
+            c.invalidation_rounds += 1
+            c.invalidations_sent += len(targets)
+            c.invalidations_filtered += len(all_pods) - len(targets)
+            c.coherence_bytes += 12 * len(targets)
+
+    def _add_table_pages(self, n: int) -> None:
+        self._table_pages += n
+        c = self.counters
+        c.table_pages_peak = max(c.table_pages_peak, self._table_pages)
 
     # ------------------------------------------------------------ translation
     def record_access(self, pod: int, logical_block: int) -> None:
@@ -203,7 +224,9 @@ class HostBlockManager:
             self.counters.fetches += 1
             self.counters.prefetched += max(0, int(newly.sum()) - 1)
             self.counters.coherence_bytes += 8 + 4 * width
-            self.sharers[tid] |= np.uint32(1 << pod)
+            if not int(self.sharers[tid]) >> pod & 1:
+                self.sharers[tid] |= np.uint32(1 << pod)
+                self._add_table_pages(1)
             self._pending_miss[pod].append(logical_block)
         elif self.mode is CoherenceMode.EAGER:
             # eager replicas are installed at mutation time; a miss here
@@ -225,23 +248,25 @@ class HostBlockManager:
         [n_pods, miss_budget] (the logical blocks pod p missed, -1 fill).
         Up to ``mutation_budget`` mutations and ``miss_budget`` misses per
         pod are drained; the rest wait for the next step."""
-        n_pods = self.spec.n_pods
-        budget = self.spec.mutation_budget
-        take, self._pending_mut = (self._pending_mut[:budget],
-                                   self._pending_mut[budget:])
-        mut_t = np.zeros((n_pods, budget), dtype=np.int32)
-        mut_i = np.zeros((n_pods, budget), dtype=np.int32)
-        mut_v = np.full((n_pods, budget), -1, dtype=np.int32)
-        mut_ok = np.zeros((n_pods, budget), dtype=bool)
-        for i, (t, s, v, pod) in enumerate(take):
-            mut_t[pod, i], mut_i[pod, i], mut_v[pod, i] = t, s, v
-            mut_ok[pod, i] = True
-        miss = np.full((n_pods, self.spec.miss_budget), -1, dtype=np.int32)
-        for pod in range(n_pods):
-            got = self._pending_miss[pod][:self.spec.miss_budget]
-            self._pending_miss[pod] = self._pending_miss[pod][len(got):]
-            miss[pod, :len(got)] = got
-        return mut_t, mut_i, mut_v, mut_ok, miss
+        with TraceAnnotation("pt.drain"):
+            n_pods = self.spec.n_pods
+            budget = self.spec.mutation_budget
+            take, self._pending_mut = (self._pending_mut[:budget],
+                                       self._pending_mut[budget:])
+            mut_t = np.zeros((n_pods, budget), dtype=np.int32)
+            mut_i = np.zeros((n_pods, budget), dtype=np.int32)
+            mut_v = np.full((n_pods, budget), -1, dtype=np.int32)
+            mut_ok = np.zeros((n_pods, budget), dtype=bool)
+            for i, (t, s, v, pod) in enumerate(take):
+                mut_t[pod, i], mut_i[pod, i], mut_v[pod, i] = t, s, v
+                mut_ok[pod, i] = True
+            miss = np.full((n_pods, self.spec.miss_budget), -1,
+                           dtype=np.int32)
+            for pod in range(n_pods):
+                got = self._pending_miss[pod][:self.spec.miss_budget]
+                self._pending_miss[pod] = self._pending_miss[pod][len(got):]
+                miss[pod, :len(got)] = got
+            return mut_t, mut_i, mut_v, mut_ok, miss
 
     # ------------------------------------------------------------ validation
     def check_invariants(self) -> None:
@@ -263,6 +288,8 @@ class HostBlockManager:
             for p in range(spec.n_pods):
                 assert not (self.present[p, tid] & ~valid).any(), \
                     f"stale replica entries on pod {p} table {tid}"
+        assert self._table_pages == self.footprint_table_pages(), \
+            "running table-page count differs from the tables"
 
     def footprint_table_pages(self) -> int:
         """Replicated table pages across pods (Table 4 analogue)."""
