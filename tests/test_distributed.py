@@ -135,6 +135,54 @@ def test_multi_pod_serve_cell():
     assert "compiled-ok" in out
 
 
+def test_gather_readonly_on_pod_mesh_matches_slice_then_gather():
+    """``gather_readonly``'s shard_map branch on a (pod=4) mesh: each pool
+    lies on its own devices, and every layer's gather reads bit for bit
+    what slicing the layer's pool out of the stack and gathering the row's
+    frames (-1 entries clamped to its pool's frame 0) would."""
+    out = run_in_subprocess("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.distributed.sharding import MULTI_POD_RULES, use_rules
+        from repro.kvcache.gather import gather_readonly
+        from repro.launch.mesh import make_mesh
+        L, PODS, F, BT, K, HD, B, MB = 3, 4, 6, 4, 2, 8, 8, 5
+        rng = np.random.default_rng(0)
+        shape = (L, PODS, F, BT, K, HD)
+        k_np = rng.normal(size=shape).astype(np.float32)
+        v_np = rng.normal(size=shape).astype(np.float32)
+        tbl = rng.integers(0, F, (B, MB)).astype(np.int32)
+        tbl[0, 3:] = -1
+        tbl[3, 1] = -1
+        tbl[6] = -1
+        mesh = make_mesh((PODS, 1, 2), ("pod", "data", "model"))
+        on = lambda *s: NamedSharding(mesh, P(*s))
+        stack_on = on(None, ("pod", "data"), None, None, "model", None)
+        with use_rules(MULTI_POD_RULES), jax.set_mesh(mesh):
+            ks = jax.device_put(jnp.asarray(k_np, jnp.bfloat16), stack_on)
+            vs = jax.device_put(jnp.asarray(v_np, jnp.bfloat16), stack_on)
+            pb = jax.device_put(jnp.asarray(tbl), on(("pod", "data"), None))
+
+            def body(_, li):
+                return None, gather_readonly(ks, vs, li, pb)
+            _, (ka, va) = jax.jit(lambda: jax.lax.scan(
+                body, None, jnp.arange(L)))()
+        assert len(ks.sharding.device_set) == 8
+        ka, va = np.asarray(ka), np.asarray(va)
+        k_ref, v_ref = np.asarray(ks), np.asarray(vs)
+        frames = np.where(tbl >= 0, tbl, 0)
+        pool = np.arange(B) // (B // PODS)
+        for li in range(L):
+            want_k = k_ref[li][pool[:, None], frames]
+            want_v = v_ref[li][pool[:, None], frames]
+            assert ka[li].shape == (B, MB, BT, K, HD)
+            assert ka[li].tobytes() == want_k.tobytes(), li
+            assert va[li].tobytes() == want_v.tobytes(), li
+        print("pod-gather-ok")
+    """)
+    assert "pod-gather-ok" in out
+
+
 def test_pod_mesh_coherence_step_matches_one_device():
     """chip_smoke.py's four-chip phase at smoke size on 4 host devices:
     eager and numaPTE coherence steps on a (pod=4) mesh with the KV pool

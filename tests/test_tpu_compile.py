@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,7 +81,10 @@ def test_pte_gather_compiles_for_v5e(spec):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_one_chip_decode_step_fits_v5e(spec):
+def _decode_step(spec, batch, max_blocks):
+    """The one-chip decode step compiled at ``batch`` rows of
+    ``max_blocks``-block tables over a pool of ``batch * max_blocks``
+    frames, and the decode state it updates."""
     cfg = get_one_chip_config("qwen3_14b")
     key = spec((2,), jnp.uint32)
 
@@ -89,12 +93,63 @@ def test_one_chip_decode_step_fits_v5e(spec):
 
     params = placed(jax.eval_shape(functools.partial(init_params, cfg), key))
     state = placed(jax.eval_shape(functools.partial(
-        init_decode_state, cfg, BATCH, BATCH * MAX_BLOCKS, MAX_BLOCKS)))
+        init_decode_state, cfg, batch, batch * max_blocks, max_blocks)))
     c = _compile(build_serve_step(cfg), params, state,
-                 spec((BATCH,), jnp.int32),
-                 spec((BATCH, MAX_BLOCKS), jnp.int32), donate_argnums=(1,))
+                 spec((batch,), jnp.int32),
+                 spec((batch, max_blocks), jnp.int32), donate_argnums=(1,))
+    return c, state
+
+
+def test_one_chip_decode_step_fits_v5e(spec):
+    c, _ = _decode_step(spec, BATCH, MAX_BLOCKS)
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
     # the donated decode state is updated in place, not copied
     assert mem.alias_size_in_bytes > 0
+
+
+def _fusions(hlo: str):
+    """(shape, op_name, ops of the fused body) of every fusion in ``hlo``,
+    the text of a compiled module."""
+    bodies, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            cur = bodies.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    out = []
+    for m in re.finditer(r"= (\w+\[[\d,]*\])\S* fusion\(.*calls=%([\w.]+)"
+                         r".*op_name=\"([^\"]*)\"", hlo):
+        ops = set(re.findall(r"= \S+ ([\w-]+)\(", "\n".join(bodies[m[2]])))
+        out.append((m[1], m[3], ops))
+    return out
+
+
+def test_decode_long_step_gathers_from_the_stack(spec):
+    """At decode_long's shapes (16 rows, 129-block tables, 2,064 frames)
+    each layer's K and V gathers read the stacked cache itself: no fusion
+    of ``kv_gather`` copies a layer's pool out of the stack first."""
+    batch, max_blocks = 16, 129
+    frames = batch * max_blocks
+    c, state = _decode_step(spec, batch, max_blocks)
+    hlo = c.as_text()
+    pool = f"bf16[{frames},{BT},{K},{HD}]"
+    pool_copies = [(shape, name) for shape, name, ops in _fusions(hlo)
+                   if "kv_gather/" in name and shape == pool
+                   and "dynamic-slice" in ops]
+    assert not pool_copies, pool_copies
+    gathers = re.findall(
+        rf"= bf16\[{batch},{max_blocks},{BT},{K},{HD}\]\S* gather\("
+        r"[^\n]*op_name=\"[^\"]*kv_gather/gather\"", hlo)
+    assert len(gathers) == 2, gathers          # one for K, one for V
+    mem = c.memory_analysis()
+    pool_bytes = frames * BT * K * HD * jnp.dtype(jnp.bfloat16).itemsize
+    assert mem.temp_size_in_bytes < 2 * pool_bytes
+    # the whole donated cache is still updated in place
+    state_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes
