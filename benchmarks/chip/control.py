@@ -30,10 +30,8 @@ def readings(cell, devices, seeds, seconds: float) -> list:
     limit), and the ``correct`` each makes; the tokens compared."""
     import jax
     from benchmarks.chip import harness, traffic
-    ref = harness.load_module(harness.HERE / "reference"
-                              / f"{cell.doc['reference']}.py")
-    eng = harness.load_module(harness.HERE / "engines"
-                              / f"{cell.doc['engine']}.py").Engine(
+    ref = harness.piece("reference", cell.doc["reference"])
+    eng = harness.piece("engines", cell.doc["engine"]).Engine(
         cell.doc, cell.mix, devices, ref)
     out = []
     for i, seed in enumerate(seeds):

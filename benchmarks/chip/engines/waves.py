@@ -1,7 +1,7 @@
 """Wave engine: drives the program's own paged-KV serving pieces with the
 wave policy of ``repro.launch.serve.serve()``.
 
-The program has no request-level API, so this adapter calls its pieces:
+The program has no request-level API, so this engine calls its pieces:
 
 - ``repro.kvcache.PagedKVManager`` (``start_sequence``, ``maybe_extend``,
   ``physical_tables``, ``finish_sequence``): the host page walk and the
@@ -21,6 +21,11 @@ then free every sequence of the wave.  Two changes: ``check_invariants``
 runs after the window, in the correctness check, and each step's tokens
 are read on the host one step behind dispatch, so one step stays in
 flight, as a server that streams tokens to its clients must.
+
+What depends on the model's architecture is not here: the adapter of the
+configuration's reference (``adapters/<reference>.py``) says which of the
+program's configurations the reference fits and maps the reference's
+weights into the program's tree.
 """
 from __future__ import annotations
 
@@ -36,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.chip import harness
 from repro.configs import get_config, get_smoke_config
 from repro.kvcache import PagedKVManager
 from repro.launch.specs import (build_prefill_step, build_serve_step,
@@ -43,32 +49,31 @@ from repro.launch.specs import (build_prefill_step, build_serve_step,
 from repro.models import init_decode_state, init_params
 from repro.pagedpt.blocktable import CoherenceMode
 
-#: model keys whose value the program's configuration must hold as run
-CHECKED_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
-                "vocab_size", "qk_norm", "ffn_act", "rope_theta",
-                "tie_embeddings", "norm", "kv_block_tokens")
+def adapter(doc: dict):
+    """The program's side of the configuration's reference
+    (``adapters/<reference>.py``): ``check(cfg, model)`` and
+    ``to_program(cfg, weights)``."""
+    return harness.piece("adapters", doc["reference"])
 
 
 def program_config(doc: dict):
     """The program's ModelConfig for a configuration file: its published
     config (``program_config``; ``program_size: smoke`` takes the CPU
-    smoke preset instead) with the file's depth and weight type.  Every
-    other size has to be the file's already, or this raises."""
+    smoke preset instead) with the file's weight type and each key the
+    file lists under ``reduced``.  Every other size the adapter checks,
+    and the head size and activation type, has to be the file's already,
+    or this raises."""
     m = doc["model"]
     get = get_smoke_config if doc.get("program_size") == "smoke" \
         else get_config
     cfg = dataclasses.replace(get(doc["program_config"]),
-                              n_layers=m["n_layers"],
-                              param_dtype=jnp.dtype(m["param_dtype"]))
-    wrong = {k: (getattr(cfg, k), m[k]) for k in CHECKED_KEYS
-             if getattr(cfg, k) != m[k]}
+                              param_dtype=jnp.dtype(m["param_dtype"]),
+                              **{k: m[k] for k in doc["reduced"]})
+    wrong = adapter(doc).check(cfg, m)
     if cfg.resolved_head_dim != m["head_dim"]:
         wrong["head_dim"] = (cfg.resolved_head_dim, m["head_dim"])
     if jnp.dtype(cfg.dtype) != jnp.dtype(m["dtype"]):
         wrong["dtype"] = (cfg.dtype, m["dtype"])
-    if cfg.family != "dense" or cfg.local_global_ratio or cfg.use_rope is \
-            False or cfg.attn_logit_softcap:
-        wrong["family"] = (cfg.family, "dense, global RoPE attention")
     if wrong:
         raise ValueError(f"the program's {doc['program_config']} differs "
                          f"from the configuration file (program, file): "
@@ -76,21 +81,9 @@ def program_config(doc: dict):
     return cfg
 
 
-def to_program(cfg, w: dict) -> dict:
-    """The benchmark's weights in the program's parameter tree (the same
-    arrays; the program's norms scale by 1 + scale, so a gain g is passed
-    as g - 1, which bfloat16 holds exactly for gains in [0.5, 2])."""
-    L = w["layers"]
-    attn = {k: L[k] for k in ("wq", "wk", "wv", "wo")}
-    if "q_norm" in L:
-        attn.update(q_norm=L["q_norm"] - 1, k_norm=L["k_norm"] - 1)
-    ffn = {k: L[k] for k in ("w_in", "w_out", "w_gate") if k in L}
-    params = {"groups": [{"norm1": {"scale": L["attn_norm"] - 1},
-                          "attn": attn,
-                          "norm2": {"scale": L["ffn_norm"] - 1},
-                          "ffn": ffn}],
-              "final_norm": {"scale": w["final_norm"] - 1},
-              "embedding": w["embed"], "lm_head": w["head"]}
+def check_tree(cfg, params: dict) -> None:
+    """Raise unless ``params`` has the tree, shapes and types of the
+    program's own ``init_params``."""
     want = jax.eval_shape(functools.partial(init_params, cfg),
                           jax.ShapeDtypeStruct((2,), jnp.uint32))
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
@@ -98,9 +91,8 @@ def to_program(cfg, w: dict) -> dict:
             jax.tree.structure(want) or jax.tree.leaves(
                 got, is_leaf=lambda x: isinstance(x, tuple)) != [
                 (a.shape, a.dtype) for a in jax.tree.leaves(want)]:
-        raise ValueError("the program's parameter tree changed; this "
+        raise ValueError("the program's parameter tree changed; the "
                          "adapter no longer fits it")
-    return params
 
 
 @dataclasses.dataclass
@@ -200,7 +192,8 @@ class WaveEngine:
         self.weights = self.params = self.state = None
         self.weights = self.ref.init_weights(self.doc["model"], seed,
                                              self.weight_sharding())
-        self.params = to_program(self.cfg, self.weights)
+        self.params = adapter(self.doc).to_program(self.cfg, self.weights)
+        check_tree(self.cfg, self.params)
         self._new_state()
 
     def _new_state(self):

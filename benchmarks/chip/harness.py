@@ -6,11 +6,19 @@ against the plain reference, and prints the result.
 Every piece is found by the name ``BENCHMARK.json`` gives it:
 
 - ``configs/<config>.json``: the configuration as run; it names its
-  ``engine`` (``engines/<engine>.py``) and its ``reference``
-  (``reference/<reference>.py``);
+  ``engine`` (``engines/<engine>.py``) and its ``reference``;
+- ``reference/<reference>.py``: the plain reference, which makes the
+  weights from the seed, computes the logits the served tokens are judged
+  by, and counts the model's FLOPs and bytes beside its equations;
+- ``adapters/<reference>.py``: the program's side of that reference:
+  which program configuration it fits, and its weights in the program's
+  tree;
 - ``traffic/<mix>.json``: the mix's parameters, read by ``traffic.py``;
 - ``metrics/<metric>.py``: one reader per metric, ``read(ctx)`` returning
   a number or None (nothing to read here).
+
+So a configuration of another architecture joins as new files: its
+configuration, reference, adapter and metrics.
 """
 from __future__ import annotations
 
@@ -20,15 +28,13 @@ import gc
 import importlib.util
 import json
 import pathlib
-import shutil
 import sys
-import tempfile
 import time
 from typing import List, Optional
 
 import numpy as np
 
-from benchmarks.chip import flops, traffic, trace_reduce
+from benchmarks.chip import traffic, trace_reduce
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -44,6 +50,11 @@ def load_module(path: pathlib.Path):
         sys.modules[name] = mod
         spec.loader.exec_module(mod)
     return sys.modules[name]
+
+
+def piece(kind: str, name: str):
+    """The piece ``<kind>/<name>.py`` of the benchmark."""
+    return load_module(HERE / kind / f"{name}.py")
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> dict:
@@ -96,11 +107,23 @@ class Spans:
         self.log.append((name, t, time.perf_counter()))
 
 
-#: the longest window a ``--trace 1`` run traces.  The TPU profiler keeps
-#: about a million device operations and drops the rest: 51 s of chat
-#: waves overflow it.  Every per-layer metric is a rate or a share, so a
-#: shorter window reads the same.
+#: the longest window a ``--trace 1`` run traces.  The TPU profiler drops
+#: device operations past a limit: 51 s of chat waves overflow it, a 24 s
+#: decode window (2.3 million operations) does not.  Every per-layer
+#: metric is a rate or a share, so a shorter window reads the same.
 TRACE_SECONDS = 20.0
+
+
+def trace_options():
+    """The profiler's options: the host's annotations (the spans) and the
+    device's operations, but not every Python call, which the profiler
+    traces by default: on a TPU v5e host that tracer slowed qwen3_14b
+    decode_long's walk from 1.66 to 2.86 ms a step and put 3.4 million
+    events in a 24 s trace."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
 
 
 def check_trace_whole(reduced, record) -> None:
@@ -127,7 +150,12 @@ class Ctx:
     chips: int
     peaks: dict
     trace: Optional[trace_reduce.Reduced] = None
-    flops = flops
+
+    @property
+    def counts(self):
+        """The cell's reference module, whose ``token_flops``,
+        ``prefill_flops`` and ``decode_bytes`` count the model's work."""
+        return piece("reference", self.cell.doc["reference"])
 
 
 def tokens_in_window(record) -> int:
@@ -216,8 +244,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     import jax
 
     marks = {"start": time.perf_counter() - t_start}
-    ref = load_module(HERE / "reference" / f"{cell.doc['reference']}.py")
-    eng_mod = load_module(HERE / "engines" / f"{cell.doc['engine']}.py")
+    ref = piece("reference", cell.doc["reference"])
+    eng_mod = piece("engines", cell.doc["engine"])
     spans = Spans()
     engine = eng_mod.Engine(cell.doc, cell.mix, devices, ref, span=spans)
     marks["engine"] = time.perf_counter() - t_start
@@ -240,19 +268,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     spans.log.clear()
     reduced = None
     if trace:
-        tdir = tempfile.mkdtemp(prefix="bench_trace_")
-        try:
-            jax.profiler.start_trace(tdir)
-            with spans("window"):
-                record = engine.run(reqs, seconds,
-                                    traffic.open_loop(cell.mix))
-            pauses.stop()
-            jax.profiler.stop_trace()
-            reduced = trace_reduce.reduce(
-                trace_reduce.events_from_dir(tdir), Spans.NAMES)
-            check_trace_whole(reduced, record)
-        finally:
-            shutil.rmtree(tdir, ignore_errors=True)
+        session = trace_reduce.Session(trace_options())
+        with spans("window"):
+            record = engine.run(reqs, seconds, traffic.open_loop(cell.mix))
+        pauses.stop()
+        reduced = trace_reduce.reduce(
+            trace_reduce.events_from_xspace(session.stop(), Spans.NAMES),
+            Spans.NAMES)
+        check_trace_whole(reduced, record)
     else:
         record = engine.run(reqs, seconds, traffic.open_loop(cell.mix))
         pauses.stop()
@@ -266,7 +289,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:
-        value = load_module(HERE / "metrics" / f"{m['name']}.py").read(ctx)
+        value = piece("metrics", m["name"]).read(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 

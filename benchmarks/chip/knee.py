@@ -25,10 +25,8 @@ sys.path[:0] = [str(pathlib.Path(__file__).resolve().parents[2]),
 
 def sweep(cell, devices, seconds: float, factors, seed: int = 1) -> list:
     from benchmarks.chip import harness, traffic
-    ref = harness.load_module(harness.HERE / "reference"
-                              / f"{cell.doc['reference']}.py")
-    eng = harness.load_module(harness.HERE / "engines"
-                              / f"{cell.doc['engine']}.py").Engine(
+    ref = harness.piece("reference", cell.doc["reference"])
+    eng = harness.piece("engines", cell.doc["engine"]).Engine(
         cell.doc, cell.mix, devices, ref)
     eng.load(seed)
     eng.warm_up()

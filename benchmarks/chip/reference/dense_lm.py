@@ -1,5 +1,5 @@
-"""Plain reference of a dense decoder LM (Qwen3, Nemotron-4), and the
-benchmark's weights.
+"""Plain reference of a dense decoder LM (Qwen3, Nemotron-4), the
+benchmark's weights, and the model's FLOP and byte counts.
 
 It imports nothing of the program under test.  ``init_weights`` makes the
 weights from the run's seed in one jitted program, in the layout below,
@@ -29,6 +29,11 @@ configuration file): the embedding is scaled by sqrt(d_model)
 matmul operand is rounded to fp8 with a scale per row of activations and
 per output channel of weights.  That is the precision step below the
 configuration's bfloat16 which the benchmark's comparison has to catch.
+
+The counts (``token_flops``, ``prefill_flops``, ``decode_bytes``) are pure
+functions of the ``model`` block, read from the shapes of these equations:
+the work and the traffic the architecture needs, not what an
+implementation does.
 """
 from __future__ import annotations
 
@@ -244,3 +249,55 @@ def logit_gaps(m: dict, weights: dict, prompt, served,
             at8 = jnp.where(better, st[4], at8)
     chosen = at8 if control else pick
     return np.asarray((best - chosen) / scale)
+
+
+# ------------------------------------------------------------------ counts
+# Model FLOPs count the work the architecture needs, two per multiply-add:
+# the projections, the feed-forward, the head at the positions whose
+# logits are used, and causal attention over the live context (QK^T and
+# PV).  They do not count work an implementation adds: padded rows,
+# masked-out scores, recomputation.  Norms, RoPE, softmax and the
+# embedding gather are left out; at these widths they are far below a
+# tenth of a percent.
+def matmul_params_per_layer(m: dict) -> int:
+    """Weights one token multiplies through in one layer."""
+    layer = weight_shapes(dict(m, n_layers=1))["layers"]
+    return sum(math.prod(s) for s in layer.values() if len(s) == 3)
+
+
+def token_flops(m: dict, context: int) -> float:
+    """One token through every layer and the head, attending to
+    ``context`` positions (itself included)."""
+    per_layer = (2 * matmul_params_per_layer(m)
+                 + 4 * m["n_heads"] * head_dim(m) * context)
+    return m["n_layers"] * per_layer + 2 * m["d_model"] * m["vocab_size"]
+
+
+def prefill_flops(m: dict, prompt_len: int, rows: int) -> float:
+    """Prefill of ``rows`` prompts of ``prompt_len`` tokens: every
+    position through the layers with causal attention, the head at the
+    last position only."""
+    S = prompt_len
+    per_layer = (2 * matmul_params_per_layer(m) * S
+                 + 4 * m["n_heads"] * head_dim(m) * S * (S + 1) // 2)
+    return rows * (m["n_layers"] * per_layer
+                   + 2 * m["d_model"] * m["vocab_size"])
+
+
+def decode_bytes(m: dict, contexts) -> int:
+    """Bytes one decode step has to move to or from HBM, one row at each
+    of ``contexts`` (the positions a row attends to, itself included):
+    every weight once, the head and the norm gains with them, but of the
+    embedding table only the rows' own rows; and in every layer each row's
+    K and V of its earlier positions read and of its new token written.
+    An implementation that gathers whole block tables, or serves padding
+    rows, moves more; that is not counted."""
+    shapes = jax.tree.leaves(weight_shapes(m),
+                             is_leaf=lambda s: isinstance(s, tuple))
+    table = m["vocab_size"] * m["d_model"]
+    weights = sum(math.prod(s) for s in shapes) - table
+    kv_token = 2 * m["n_kv_heads"] * head_dim(m) * \
+        jnp.dtype(m["dtype"]).itemsize
+    return (jnp.dtype(m["param_dtype"]).itemsize
+            * (weights + len(contexts) * m["d_model"])
+            + m["n_layers"] * kv_token * sum(contexts))

@@ -25,6 +25,7 @@ def cell(*, arrival="offline", batch=4, mesh_pods=1, prompt_len=32,
     doc = {"program_config": "qwen3_14b", "program_size": "smoke",
            "engine": "waves", "reference": "dense_lm",
            "model": copy.deepcopy(MODEL),
+           "reduced": {"n_layers": "the preset's own depth"},
            "server": {"batch": batch, "n_pods": 4, "mode": "numapte",
                       "mesh_pods": mesh_pods},
            "limits": {"token_gap": limit}}

@@ -126,5 +126,40 @@ def test_metric_arithmetic():
 
 
 def smoke_flops(m, context):
-    from benchmarks.chip import flops
-    return flops.token_flops(m, context)
+    return harness.piece("reference", "dense_lm").token_flops(m, context)
+
+
+def test_decode_roofline_arithmetic():
+    """The record of ``test_metric_arithmetic``: four decode steps of two
+    rows at contexts 5 and 6, in 0.5 s of device time.  At smoke size a
+    step's bytes bound it, as at the cells' sizes."""
+    reqs = {i: traffic.Request(i, 0.0, np.zeros(4, np.int32), 3)
+            for i in range(4)}
+    waves = [Wave([2 * i, 2 * i + 1], 0.0, steps=2) for i in range(2)]
+    rec = Record(0.0, 1.0, 1.0, False, reqs, waves, {}, 4)
+    trace = trace_reduce.Reduced(
+        window_s=1.0, busy_s=0.9, n_devices=1, ops=[], idle_by_span=[],
+        programs={"bench_decode": {"calls": 4, "s": 0.5,
+                                   "collective_s": 0.0}})
+    ctx = harness.Ctx(smoke.cell(), rec, [], 0.0, 1, smoke.PEAK, trace)
+    m = smoke.MODEL
+    # weights once a step, but the embedding table: 2 layers x (wq, wo
+    # 64 x 64; wk, wv 64 x 32; w_in, w_gate, w_out 64 x 160; four gains
+    # of 64 and 16) + the head 64 x 512 + the final gain, 2 B each
+    weights = 2 * (2 * (2 * 64 * 64 + 2 * 64 * 32 + 3 * 64 * 160
+                        + 2 * 64 + 2 * 16) + 64 * 512 + 64)
+    # two embedding rows, and K + V (2 x 2 heads x 16 x 2 B) per layer
+    # and position
+    step = {c: weights + 2 * 64 * 2 + 2 * 128 * 2 * c for c in (5, 6)}
+    assert harness.piece("reference", "dense_lm").decode_bytes(
+        m, [5, 5]) == step[5]
+    flops = {c: 2 * smoke_flops(m, c) for c in (5, 6)}
+    assert all(flops[c] / smoke.PEAK["bf16_flops_per_s"] < 0.1 * step[c]
+               / smoke.PEAK["hbm_bytes_per_s"] for c in (5, 6))
+    want = 2 * (step[5] + step[6]) / smoke.PEAK["hbm_bytes_per_s"]
+    value = harness.piece("metrics", "decode_roofline").read(ctx)
+    assert value == pytest.approx(100 * want / 0.5)
+    # a trace with no decode call has nothing to read
+    ctx.trace.programs["bench_decode"] = {"calls": 0, "s": 0.0,
+                                          "collective_s": 0.0}
+    assert harness.piece("metrics", "decode_roofline").read(ctx) is None

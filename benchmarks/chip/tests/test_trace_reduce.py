@@ -102,6 +102,22 @@ def test_recorded_v5e_trace():
     assert sum(idle.values()) == pytest.approx(r.window_s - r.busy_s)
 
 
+def test_reading_only_what_reduce_reads_reduces_alike():
+    """Read with the span names, the recorded trace keeps its device
+    operations (by their HLO names), modules and spans, and drops the
+    rest, and reduces exactly as the whole trace does."""
+    path = str(DATA / "probe.xplane.pb")
+    whole = tr.events_from_file(path)
+    kept = tr.events_from_file(path, Spans.NAMES)
+    assert 0 < len(kept) < len(whole)
+    assert {e.line for e in kept if tr.DEVICE_PLANE.match(e.plane)} == {
+        tr.OPS_LINE, tr.MODULES_LINE}
+    assert all(e.name in Spans.NAMES for e in kept
+               if not tr.DEVICE_PLANE.match(e.plane))
+    assert all(" = " not in e.name for e in kept)
+    assert tr.reduce(kept, Spans.NAMES) == tr.reduce(whole, Spans.NAMES)
+
+
 def test_a_trace_that_lost_calls_is_refused():
     """One device ran one prefill and one decode step: a window that
     dispatched a second step lost events from the trace."""

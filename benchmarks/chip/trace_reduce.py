@@ -1,5 +1,5 @@
-"""Reduction of a JAX profiler trace (``.xplane.pb``) to the numbers the
-per-layer metrics read.
+"""Reduction of a JAX profiler trace (an XSpace, as in ``.xplane.pb``) to
+the numbers the per-layer metrics read.
 
 The trace is first flattened into plain ``Event`` tuples (plane, line,
 name, start, duration in ns); everything after that works on those, so a
@@ -22,11 +22,10 @@ test can feed a recorded trace or a handful of events.
 from __future__ import annotations
 
 import dataclasses
-import glob
-import os
 import re
 from collections import defaultdict
-from typing import Dict, Iterable, List, NamedTuple, Sequence, Tuple
+from typing import (Dict, Iterable, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:\d+$")
 OPS_LINE = "XLA Ops"
@@ -48,24 +47,72 @@ class Event(NamedTuple):
     dur_ns: float
 
 
-def events_from_file(path: str) -> List[Event]:
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+class Session:
+    """A profiler session whose trace stays in memory: ``stop()`` returns
+    the serialized XSpace.  ``jax.profiler.stop_trace`` also writes it out
+    and converts it for the trace viewer: for a 24 s decode window on a
+    TPU v5e that stop took 77 s and 6.9 GB of the host's memory, this one
+    36 s and 1.9 GB."""
+
+    def __init__(self, options):
+        import jax
+        from jax._src.lib import _profiler
+        # the backends first, as ``jax.profiler.start_trace`` does, or the
+        # TPU's tracer is not set up and the trace holds no device plane
+        jax.devices()
+        self._session = _profiler.ProfilerSession(options)
+
+    def stop(self) -> bytes:
+        return self._session.stop()
+
+
+def events_from_profile(pd, span_names: Optional[Sequence[str]] = None
+                        ) -> List[Event]:
+    """The events of a ``jax.profiler.ProfileData``.  With ``span_names``,
+    only those ``reduce`` reads: the device planes' operations and
+    modules, each operation by its HLO name (``op_name``), and the host
+    events of those names.  The reduction of what is kept equals that of
+    the whole trace; a 24 s decode window has 2.3 million operations,
+    each named by its whole instruction."""
     out = []
+    names: Dict[str, str] = {}      # one string for each short name
     for plane in pd.planes:
+        pname = plane.name
+        device = bool(DEVICE_PLANE.match(pname))
         for line in plane.lines:
-            for e in line.events:
-                out.append(Event(plane.name, line.name, e.name,
-                                 float(e.start_ns), float(e.duration_ns)))
+            lname = line.name
+            if span_names is None:
+                out.extend(Event(pname, lname, e.name, float(e.start_ns),
+                                 float(e.duration_ns)) for e in line.events)
+            elif not device:
+                out.extend(Event(pname, lname, e.name, float(e.start_ns),
+                                 float(e.duration_ns))
+                           for e in line.events if e.name in span_names)
+            elif lname == OPS_LINE:
+                for e in line.events:
+                    n = op_name(e.name)
+                    out.append(Event(pname, lname, names.setdefault(n, n),
+                                     float(e.start_ns),
+                                     float(e.duration_ns)))
+            elif lname == MODULES_LINE:
+                out.extend(Event(pname, lname, e.name, float(e.start_ns),
+                                 float(e.duration_ns)) for e in line.events)
     return out
 
 
-def events_from_dir(trace_dir: str) -> List[Event]:
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    return [e for p in sorted(paths) for e in events_from_file(p)]
+def events_from_file(path: str,
+                     span_names: Optional[Sequence[str]] = None
+                     ) -> List[Event]:
+    from jax.profiler import ProfileData
+    return events_from_profile(ProfileData.from_file(path), span_names)
+
+
+def events_from_xspace(xspace: bytes,
+                       span_names: Optional[Sequence[str]] = None
+                       ) -> List[Event]:
+    from jax.profiler import ProfileData
+    return events_from_profile(ProfileData.from_serialized_xspace(xspace),
+                               span_names)
 
 
 def op_name(event: str) -> str:
