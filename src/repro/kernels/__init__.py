@@ -1,5 +1,6 @@
 """Pallas kernels of the serving path (``flash_attention``,
-``paged_attention``, ``pte_gather``) plus the jitted ``fifo_miss`` loop.
+``paged_attention``, ``pte_gather``, ``expert_ffn``) plus the jitted
+``fifo_miss`` loop.
 
 Each ``ops`` wrapper compiles its kernel for the TPU, and interprets it
 only where JAX's default backend is the CPU (``interpret_on_cpu``)."""

@@ -140,32 +140,32 @@ def gather_readonly(k_stack: jax.Array, v_stack: jax.Array,
 
 
 def _commit_plain(k_stack, v_stack, k_new, v_new, frame, slot, valid=None):
-    """k_stack [L,F,bt,K,hd]; k_new [L,B,K,hd]; per-token DUS writes.
-    ``valid`` [B] masks inactive (padding) rows into write-backs of the
-    slab's own bytes, so unmapped rows never touch frame 0."""
-    L, B = k_new.shape[:2]
+    """k_stack [L,F,bt,K,hd]; k_new [L,B,K,hd]; one DUS per row writes its
+    new token into every layer at once (the row's frame and slot are the
+    same in all layers), so the loop runs B times, not L * B: each of its
+    iterations is a handful of device operations.  ``valid`` [B] masks
+    inactive (padding) rows into write-backs of the slab's own bytes, so
+    unmapped rows never touch frame 0."""
+    B = k_new.shape[1]
     if valid is None:
         valid = jnp.ones((B,), bool)
+    zero = jnp.zeros((), frame.dtype)
 
     def write(stacks, args):
         ks, vs = stacks
-        li, b, kv_, vv_ = args
-        idx = (li, frame[b], slot[b], jnp.zeros((), li.dtype),
-               jnp.zeros((), li.dtype))
+        b, kv_, vv_ = args                       # kv_ [L, K, hd]
+        idx = (zero, frame[b], slot[b], zero, zero)
         kv_ = jnp.where(valid[b], kv_.astype(ks.dtype),
-                        ks[li, frame[b], slot[b]])
+                        ks[:, frame[b], slot[b]])
         vv_ = jnp.where(valid[b], vv_.astype(vs.dtype),
-                        vs[li, frame[b], slot[b]])
-        ks = jax.lax.dynamic_update_slice(ks, kv_[None, None, None], idx)
-        vs = jax.lax.dynamic_update_slice(vs, vv_[None, None, None], idx)
+                        vs[:, frame[b], slot[b]])
+        ks = jax.lax.dynamic_update_slice(ks, kv_[:, None, None], idx)
+        vs = jax.lax.dynamic_update_slice(vs, vv_[:, None, None], idx)
         return (ks, vs), None
 
-    li = jnp.repeat(jnp.arange(L), B)
-    bi = jnp.tile(jnp.arange(B), L)
-    flat_k = k_new.reshape((L * B,) + k_new.shape[2:])
-    flat_v = v_new.reshape((L * B,) + v_new.shape[2:])
     (k_stack, v_stack), _ = jax.lax.scan(
-        write, (k_stack, v_stack), (li, bi, flat_k, flat_v))
+        write, (k_stack, v_stack),
+        (jnp.arange(B), jnp.swapaxes(k_new, 0, 1), jnp.swapaxes(v_new, 0, 1)))
     return k_stack, v_stack
 
 

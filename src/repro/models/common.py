@@ -56,6 +56,11 @@ class ModelConfig:
     n_shared_experts: int = 0
     first_dense_layers: int = 0              # kimi: leading dense layers
     moe_capacity_factor: float = 1.25
+    # the experts of each layer this chip holds: ``experts_held`` of them
+    # from index ``first_held_expert`` (None: all ``n_experts``).  The
+    # router keeps all ``n_experts`` outputs; see ``models.moe``.
+    experts_held: Optional[int] = None
+    first_held_expert: int = 0
     # ssm (mamba2 SSD)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -85,6 +90,11 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
 
     @property
     def q_per_kv(self) -> int:

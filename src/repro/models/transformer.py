@@ -26,7 +26,7 @@ from .attention import (attn_decode_paged, attn_decode_ring, attn_forward,
 from .common import (KeyGen, LayerGroup, ModelConfig, _dense, apply_norm,
                      init_norm, layer_groups, stack_layer_params)
 from .ffn import ffn_forward, init_ffn
-from .moe import init_moe, moe_forward
+from .moe import EXPERT_WEIGHTS, init_moe, moe_forward, moe_serve
 from .rglru import init_rglru, rglru_decode, rglru_forward
 from .ssm import init_ssd, ssd_decode, ssd_forward
 
@@ -80,7 +80,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> PyTree:
 
 
 def param_count(cfg: ModelConfig) -> int:
+    """Parameters of the published model, whatever share of its experts
+    ``cfg`` holds."""
     import math
+    cfg = dataclasses.replace(cfg, experts_held=None, first_held_expert=0)
     shapes = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
     return sum(math.prod(l.shape) for l in jax.tree.leaves(shapes))
@@ -334,6 +337,7 @@ def _decode_group(cfg: ModelConfig, g: LayerGroup, gp: PyTree,
             from ..kvcache.gather import commit_token_writes
             from .attention import attn_decode_paged_ro
             k_stack, v_stack = cache["k_slabs"], cache["v_slabs"]
+            gp_scan, experts = _experts_out_of_scan(g, gp)
 
             def body(x, xs):
                 lp, li, *cross = xs
@@ -350,10 +354,10 @@ def _decode_group(cfg: ModelConfig, g: LayerGroup, gp: PyTree,
                     h = apply_norm(cfg, x, lp["norm_cross"])
                     a = _cross_decode(cfg, lp["cross"], h, ck, cv)
                     x = x + a
-                return _ffn_block(cfg, g, lp, x), (kn, vn)
+                return _ffn_block(cfg, g, lp, x, experts, li), (kn, vn)
 
             L = jax.tree.leaves(gp)[0].shape[0]
-            xs = (gp, jnp.arange(L))
+            xs = (gp_scan, jnp.arange(L))
             if g.kind == "dec_attn":
                 xs = xs + (cache["cross_k"], cache["cross_v"])
             x, (k_new, v_new) = jax.lax.scan(body, x, xs)
@@ -423,13 +427,32 @@ def _decode_group(cfg: ModelConfig, g: LayerGroup, gp: PyTree,
     raise ValueError(g.kind)
 
 
+def _experts_out_of_scan(g: LayerGroup, gp: PyTree):
+    """An expert group's params without its stacked expert weights, for
+    the layer scan to slice, and those weights, which the grouped-FFN
+    kernel reads a layer at a time from the stack by the layer's index: a
+    slice taken by the scan would be copied every step before the kernel
+    reads it."""
+    if not g.moe:
+        return gp, None
+    moe = gp["moe"]
+    return (dict(gp, moe={k: v for k, v in moe.items()
+                          if k not in EXPERT_WEIGHTS}),
+            {k: moe[k] for k in EXPERT_WEIGHTS})
+
+
 @jax.named_scope("ffn")
-def _ffn_block(cfg: ModelConfig, g: LayerGroup, lp: PyTree,
-               x: jax.Array) -> jax.Array:
-    """Pre-norm FFN (or MoE) sublayer with its residual."""
+def _ffn_block(cfg: ModelConfig, g: LayerGroup, lp: PyTree, x: jax.Array,
+               experts: Optional[PyTree] = None,
+               layer: Optional[jax.Array] = None) -> jax.Array:
+    """Pre-norm FFN (or served expert) sublayer with its residual; an
+    expert layer's scopes ``router`` and ``experts`` nest in ``ffn``.
+    ``experts``: the group's stacked expert weights, of which ``layer``'s
+    are used (``_experts_out_of_scan``)."""
     h = apply_norm(cfg, x, lp["norm2"])
     if g.moe:
-        f, _ = moe_forward(cfg, lp["moe"], h)
+        p = lp["moe"] if experts is None else {**lp["moe"], **experts}
+        f = moe_serve(cfg, p, h, layer)
     else:
         f = ffn_forward(cfg, lp["ffn"], h)
     return x + f
@@ -485,9 +508,11 @@ def _prefill_group(cfg, g, gp, cache, x, positions, phys_blocks):
         from ..kvcache.gather import (scatter_prefill_plain,
                                       scatter_prefill_pooled)
 
+        gp_scan, experts = _experts_out_of_scan(g, gp)
+
         def body(carry, xs):
             x = carry
-            lp, ks, vs = xs
+            lp, li, ks, vs = xs
             with jax.named_scope("attn_qkv"):
                 h = apply_norm(cfg, x, lp["norm1"])
             a = attn_forward(cfg, lp["attn"], h, positions, window=None,
@@ -502,10 +527,11 @@ def _prefill_group(cfg, g, gp, cache, x, positions, phys_blocks):
             ks, vs = scatter(ks, vs, k, v, phys_blocks, positions, bt)
             with jax.named_scope("attn"):
                 x = x + a
-            return _ffn_block(cfg, g, lp, x), (ks, vs)
+            return _ffn_block(cfg, g, lp, x, experts, li), (ks, vs)
 
         x, (ks, vs) = jax.lax.scan(
-            body, x, (gp, cache["k_slabs"], cache["v_slabs"]))
+            body, x, (gp_scan, jnp.arange(g.n_layers), cache["k_slabs"],
+                      cache["v_slabs"]))
         return x, dict(cache, k_slabs=ks, v_slabs=vs)
 
     # other kinds: run the layer forward AND capture its decode state inside

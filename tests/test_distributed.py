@@ -205,3 +205,53 @@ def test_pod_mesh_coherence_step_matches_one_device():
         print("pod-mesh-ok", sorted(r["tokens"]))
     """)
     assert "pod-mesh-ok ['eager', 'numapte', 'one_device']" in out
+
+
+def test_expert_layer_on_a_mesh_matches_one_device():
+    """The served expert layer on a (data=2, model=4) host mesh, its held
+    experts 2-9 split over 'model' as the parameters are (2 a device):
+    prefill and a decode step give the logits of one device holding all 8,
+    and the decode step sums the shares over 'model'."""
+    out = run_in_subprocess("""
+        import dataclasses
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs import get_smoke_config
+        from repro.distributed.sharding import use_rules
+        from repro.launch.mesh import make_mesh
+        from repro.launch.specs import make_rules, param_shardings
+        from repro.models import init_decode_state, init_params
+        from repro.models.transformer import decode_step, prefill
+        cfg = dataclasses.replace(
+            get_smoke_config("qwen3_moe_235b_a22b"), n_experts=16,
+            experts_held=8, first_held_expert=2, dtype=jnp.float32)
+        mesh = make_mesh((2, 4), ("data", "model"))
+        B, MB, S = 8, 4, 12
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.default_rng(0)
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S)),
+                             jnp.int32)
+        nxt = jnp.asarray(rng.integers(0, cfg.vocab_size, (B,)), jnp.int32)
+        phys = jnp.asarray(np.arange(B * MB, dtype=np.int32).reshape(B, MB))
+
+        def run(params):
+            state = init_decode_state(cfg, B, B * MB, MB)
+            pre = jax.jit(lambda p, s: prefill(cfg, p, tokens, s, phys))
+            dec = jax.jit(lambda p, s: decode_step(cfg, p, s, nxt, phys))
+            a, state = pre(params, state)
+            b, _ = dec(params, state)
+            hlo = dec.lower(params, state).compile().as_text()
+            return np.asarray(a), np.asarray(b), hlo
+
+        one = run(params)
+        with use_rules(make_rules(cfg, mesh)), jax.set_mesh(mesh):
+            placed = jax.tree.map(jax.device_put, params,
+                                  param_shardings(params, mesh))
+            we_in = placed["groups"][0]["moe"]["we_in"]
+            assert we_in.shape[1] == 8
+            assert we_in.addressable_shards[0].data.shape[1] == 2
+            many = run(placed)
+        for got, want in zip(many[:2], one[:2]):
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        print("expert-mesh-ok", "all-reduce" in many[2])
+    """)
+    assert "expert-mesh-ok True" in out

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_one_chip_config
+from repro.kernels.expert_ffn.kernel import expert_ffn_kernel
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.kernels.pte_gather.kernel import pte_gather_kernel
@@ -79,6 +80,105 @@ def test_pte_gather_compiles_for_v5e(spec):
     c = _compile(functools.partial(pte_gather_kernel, prefetch_degree=3),
                  spec((64, 512), jnp.int32), spec((256,), jnp.int32))
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("rows", [64 * 8, 2048 * 8])
+def test_expert_ffn_compiles_for_v5e(spec, rows):
+    """Qwen3-235B-A22B's 8 held experts (d 4096, expert width 1536) in an
+    8-layer stack, over a decode step's routed rows (64 x 8) and a prefill
+    chunk's (2048 x 8): three Pallas calls, named as the benchmark's
+    reader finds them."""
+    bf = jnp.bfloat16
+    c = _compile(expert_ffn_kernel, spec((rows, 4096), bf),
+                 spec((8,), jnp.int32), spec((8, 8, 4096, 1536), bf),
+                 spec((8, 8, 4096, 1536), bf), spec((8, 8, 1536, 4096), bf),
+                 spec((1,), jnp.int32))
+    calls = re.findall(r"%(\S+) = \S+ custom-call\([^\n]*tpu_custom_call",
+                       c.as_text())
+    assert [re.sub(r"\.\d+$", "", n) for n in calls] == ["gmm"] * 3, calls
+
+
+def test_expert_decode_step_reads_the_stacked_experts(spec, monkeypatch):
+    """qwen3_moe_235b_a22b's one-chip decode step at its cell's shapes (64
+    rows, 129-block tables): the grouped-FFN kernel reads each layer's
+    experts from the 8-layer stack itself; no operation copies a layer's
+    experts out of it first (a scan's slice would be one, every step)."""
+    import repro.kernels.expert_ffn.ops as expert_ops
+    # the ops wrapper interprets the kernel where JAX's default backend is
+    # the CPU; here the step is compiled for the described chip
+    monkeypatch.setattr(expert_ops, "interpret_on_cpu", lambda: False)
+    cfg = get_one_chip_config("qwen3_moe_235b_a22b")
+    batch, max_blocks = 64, 129
+    placed = functools.partial(jax.tree.map,
+                               lambda l: spec(l.shape, l.dtype))
+    params = placed(jax.eval_shape(functools.partial(init_params, cfg),
+                                   spec((2,), jnp.uint32)))
+    state = placed(jax.eval_shape(functools.partial(
+        init_decode_state, cfg, batch, batch * max_blocks, max_blocks)))
+    hlo = _compile(build_serve_step(cfg), params, state,
+                   spec((batch,), jnp.int32),
+                   spec((batch, max_blocks), jnp.int32),
+                   donate_argnums=(1,)).as_text()
+    kernels = [l for l in hlo.splitlines()
+               if re.search(r"%gmm\S* = .*tpu_custom_call", l)]
+    assert len(kernels) == 3, kernels
+    for l in kernels:   # the 8-layer stack as 64 groups
+        assert "bf16[64,4096,1536]" in l or "bf16[64,1536,4096]" in l, l
+    one_layer = re.compile(r"= bf16\[8,(4096,1536|1536,4096)\]")
+    assert not [l for l in hlo.splitlines() if one_layer.search(l)]
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_expert_steps_compile_on_an_expert_mesh(topo, monkeypatch, step):
+    """Qwen3-235B-A22B's expert layers at published widths on a 2x2 v5e
+    mesh, 16 held experts split over 'model' (8 a chip) and the batch
+    over 'data': XLA cannot partition the grouped-FFN kernel, so the
+    layer runs it in shard_map, where each chip's kernel reads its own 8
+    experts."""
+    import dataclasses
+
+    import repro.kernels.expert_ffn.ops as expert_ops
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.distributed.sharding import use_rules
+    from repro.launch.mesh import make_mesh
+    from repro.launch.specs import (build_prefill_step, make_rules,
+                                    param_shardings, state_shardings)
+    monkeypatch.setattr(expert_ops, "interpret_on_cpu", lambda: False)
+    cfg = dataclasses.replace(get_one_chip_config("qwen3_moe_235b_a22b"),
+                              n_layers=2, experts_held=16)
+    mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices)
+    batch, max_blocks = 16, 9
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda l, s: jax.ShapeDtypeStruct(
+            l.shape, l.dtype, sharding=s), tree, shardings)
+
+    def rows(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=NamedSharding(
+            mesh, P("data", *(None,) * (len(shape) - 1))))
+
+    rules = make_rules(cfg, mesh)
+    with use_rules(rules), jax.set_mesh(mesh):
+        params = jax.eval_shape(functools.partial(init_params, cfg),
+                                jax.ShapeDtypeStruct((2,), jnp.uint32))
+        params = placed(params, param_shardings(params, mesh))
+        state = jax.eval_shape(functools.partial(
+            init_decode_state, cfg, batch, batch * max_blocks, max_blocks,
+            n_pools=2))
+        state = placed(state, state_shardings(cfg, state, mesh, rules,
+                                              sp=False))
+        if step == "decode":
+            fn, tokens = build_serve_step(cfg), rows(batch)
+        else:
+            fn, tokens = build_prefill_step(cfg), rows(batch, 128)
+        hlo = _compile(fn, params, state, tokens, rows(batch, max_blocks),
+                       donate_argnums=(1,)).as_text()
+    kernels = [l for l in hlo.splitlines()
+               if re.search(r"%gmm\S* = .*tpu_custom_call", l)]
+    assert kernels
+    for l in kernels:   # the 2-layer stack of this chip's 8, as 16 groups
+        assert re.search(r"bf16\[16,(4096,1536|1536,4096)\]", l), l
 
 
 def _decode_step(spec, batch, max_blocks):
