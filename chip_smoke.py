@@ -203,7 +203,7 @@ def pod_mesh_phase(cfg, devices, *, batch: int = 8, prompt_len: int = 512,
                                   n_pools=n_pods)
 
     def kv_slabs(state):
-        """The KV pools, [L, n_pods, F, bt, K, hd] each."""
+        """The KV pools, [L, n_pods, K, F, bt, hd] each."""
         return [c[k] for c in state.caches for k in sorted(c)
                 if k.endswith("_slabs")]
 

@@ -10,8 +10,9 @@ kernel-level analogue of numaPTE keeping walks local.
 Grid: (B, num_blocks).  The inner dimension is sequential on TPU, so the
 online-softmax accumulators live in VMEM scratch across iterations.
 
-Block shapes: KV slab block [1, bt, K, hd] with bt*K*hd*2B per operand
-(e.g. 16*8*128*2 = 32KB) — two operands in VMEM double-buffered = 128KB,
+Block shapes: KV slab block [K, 1, bt, hd] (every head's copy of one
+frame, heads-major as the pool is laid out) with K*bt*hd*2B per operand
+(e.g. 8*16*128*2 = 32KB) — two operands in VMEM double-buffered = 128KB,
 comfortably inside the ~16MB VMEM budget; q/out blocks are [1, H, hd].
 MXU alignment: hd is 64/112/128/256 across the pool; contractions are over
 hd (lane-aligned at 128 for the common configs).
@@ -51,13 +52,13 @@ def _kernel(blocks_ref, lens_ref,            # scalar prefetch
     @pl.when(block_live)
     def _attend():
         q = q_ref[0].astype(jnp.float32)             # [H, hd]
-        k = k_ref[0].astype(jnp.float32)             # [bt, K, hd]
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[:, 0].astype(jnp.float32)          # [K, bt, hd]
+        v = v_ref[:, 0].astype(jnp.float32)
         H, hd = q.shape
         G = H // n_kv
         qg = q.reshape(n_kv, G, hd)
         s = jax.lax.dot_general(qg, k,
-                                (((2,), (2,)), ((0,), (1,))),
+                                (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
         # dims: [K, G, bt]
         s = s * scale
@@ -73,7 +74,7 @@ def _kernel(blocks_ref, lens_ref,            # scalar prefetch
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
         pv = jax.lax.dot_general(p, v,
-                                 (((2,), (0,)), ((0,), (1,))),
+                                 (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
         # dims: [K, G, hd]
         acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
@@ -93,10 +94,10 @@ def paged_attention_kernel(q: jax.Array, k_slabs: jax.Array,
                            seq_lens: jax.Array, *,
                            window: Optional[int] = None,
                            interpret: bool = False) -> jax.Array:
-    """q: [B,H,hd]; k/v_slabs: [N,bt,K,hd]; block_tables: [B,MB] physical
+    """q: [B,H,hd]; k/v_slabs: [K,N,bt,hd]; block_tables: [B,MB] physical
     frames; seq_lens: [B].  Returns [B,H,hd] float32."""
     B, H, hd = q.shape
-    N, bt, K, _ = k_slabs.shape
+    K, N, bt, _ = k_slabs.shape
     MB = block_tables.shape[1]
     G = H // K
     scale = hd ** -0.5
@@ -111,10 +112,10 @@ def paged_attention_kernel(q: jax.Array, k_slabs: jax.Array,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, H, hd), lambda b, i, bl, ln: (b, 0, 0)),
-                pl.BlockSpec((1, bt, K, hd),
-                             lambda b, i, bl, ln: (jnp.maximum(bl[b, i], 0), 0, 0, 0)),
-                pl.BlockSpec((1, bt, K, hd),
-                             lambda b, i, bl, ln: (jnp.maximum(bl[b, i], 0), 0, 0, 0)),
+                pl.BlockSpec((K, 1, bt, hd),
+                             lambda b, i, bl, ln: (0, jnp.maximum(bl[b, i], 0), 0, 0)),
+                pl.BlockSpec((K, 1, bt, hd),
+                             lambda b, i, bl, ln: (0, jnp.maximum(bl[b, i], 0), 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, H, hd), lambda b, i, bl, ln: (b, 0, 0)),
             scratch_shapes=[

@@ -1,12 +1,24 @@
 """Pool-partitioned KV slab update + gather (the numaPTE sharding).
 
-The KV pool is partitioned per data shard — ``slabs [n_pools, F_local, bt,
-K, hd]`` with the pool axis mapped to 'data' — and every sequence's frames
-live in its own shard's pool.  This is the device-level mirror of the
-paper's partitioned page tables (Section 3.3: each node owns the tables of
-its own data, no cross-node traffic in the common case): the decode-step
-gather is provably pool-local, so SPMD emits *zero* collectives for KV
-reads, instead of the all-gather a flat sharded pool would force.
+The KV pool is laid out heads before frames: one layer's slabs are
+``[K, F, bt, hd]`` and the decode state stacks them as ``[L, K, F, bt,
+hd]``.  Pool-partitioned, each data shard has its own pool of ``F_local``
+frames — ``[L, n_pools, K, F_local, bt, hd]``, the pool axis mapped to
+'data' — and every sequence's frames live in its own shard's pool.  This
+is the device-level mirror of the paper's partitioned page tables
+(Section 3.3: each node owns the tables of its own data, no cross-node
+traffic in the common case): the decode-step gather is provably
+pool-local, so SPMD emits *zero* collectives for KV reads, instead of the
+all-gather a flat sharded pool would force.
+
+Heads-major is what the attention dot reads for any head count: the
+gathered blocks come out ``[B, K, nb, bt, hd]`` and every minor tile is a
+``[bt, hd]`` block, so the TPU compiler keeps the pool in the layout it is
+stored in.  A decode step writes one ``hd`` row at (layer, head, frame,
+slot) per token (``_write_rows``), a prefill whole ``[bt, hd]`` blocks at
+(layer, head, frame) (``_write_blocks``): a write that spans the layer or
+the head axis makes the compiler relay out the whole stack to put that
+axis beside ``hd``.
 
 ``update_gather_pooled`` runs under shard_map over ('data',) nested in the
 jitted step; head_dim stays sharded over 'model' outside the map.
@@ -17,6 +29,7 @@ writes) and ``kv_scatter`` (the prefill's writes).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Tuple
 
 import jax
@@ -33,52 +46,111 @@ def _on_mesh(mesh, axis) -> bool:
     return mesh is not None and all(n in mesh.axis_names for n in names)
 
 
+def _pool_of_rows(n_rows: int, n_pools: int) -> jax.Array:
+    """The pool of each batch row: the rows split evenly over the pools,
+    in order, as the batch axis is sharded in lockstep with the pools."""
+    return jnp.arange(n_rows) // max(n_rows // n_pools, 1)
+
+
+def _token_frames(phys_blocks: jax.Array, positions: jax.Array,
+                  block_tokens: int, n_frames: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """(frame, slot) of each row's token at ``positions`` [B] under the
+    tables ``phys_blocks`` [B, MB].  A token whose block is unmapped (-1:
+    inactive or padding rows) gets frame ``n_frames``, outside the pool,
+    so ``_write_rows`` drops it: it never touches frame 0."""
+    blk = jnp.clip(positions // block_tokens, 0, phys_blocks.shape[1] - 1)
+    frame = jnp.take_along_axis(phys_blocks, blk[:, None], axis=1)[:, 0]
+    return (jnp.where(frame >= 0, frame, n_frames),
+            positions % block_tokens)
+
+
+def _write_rows(slabs: jax.Array, rows: jax.Array, frame: jax.Array,
+                slot: jax.Array, pool: Optional[jax.Array] = None
+                ) -> jax.Array:
+    """Write ``rows`` [(L,) B, K, hd] into ``slabs`` [(L,) (P,) K, F, bt,
+    hd] as one scatter: head k of row b's token in layer l lands at (l,
+    pool[b], k, frame[b], slot[b]).  Each update is a single ``hd`` row,
+    so no write spans the layer or the head axis, and the number of
+    operations does not grow with depth times batch.  ``frame`` == F
+    drops the token."""
+    idx = (jnp.arange(rows.shape[-2]), frame[:, None], slot[:, None])
+    if pool is not None:
+        idx = (pool[:, None],) + idx
+    if rows.ndim == 4:                           # a leading layer axis
+        idx = (jnp.arange(rows.shape[0])[:, None, None],) + idx
+    return slabs.at[idx].set(rows.astype(slabs.dtype), mode="drop")
+
+
+def _write_blocks(stack: jax.Array, kv: jax.Array, frame: jax.Array,
+                  pool: Optional[jax.Array] = None) -> jax.Array:
+    """Write a prompt's K or V, kv [L, B, S, K, hd] at positions 0..S-1,
+    into ``stack`` [L, (P,) K, F, bt, hd] as one scatter of whole [bt, hd]
+    blocks: block i of row b's head k in layer l lands at (l, pool[b], k,
+    frame[b, i]).  Each update is one tile and none spans the layer or the
+    head axis; single-row updates, 16 times as many, took the TPU
+    compiler eight times as long.  A prompt that ends inside a block
+    fills the block's tail with zeros, which no read attends before a
+    decode step writes there.  ``frame`` == F drops the block."""
+    L, B, S, K, hd = kv.shape
+    nb = frame.shape[1]
+    bt = stack.shape[-2]
+    kv = jnp.pad(kv, ((0, 0), (0, 0), (0, nb * bt - S), (0, 0), (0, 0)))
+    kv = kv.reshape(L, B, nb, bt, K, hd).transpose(0, 1, 2, 4, 3, 5)
+    idx = (jnp.arange(K), frame[:, :, None])
+    if pool is not None:
+        idx = (pool[:, None, None],) + idx
+    idx = (jnp.arange(L)[:, None, None, None],) + idx
+    return stack.at[idx].set(kv.astype(stack.dtype), mode="drop")
+
+
+def _gather_layer(stack: jax.Array, layer_idx, frames: jax.Array,
+                  pool: Optional[jax.Array] = None) -> jax.Array:
+    """Row b's blocks ``frames[b]`` [B, nb] of layer ``layer_idx``'s pool
+    (pool ``pool[b, i]``'s, where pooled), every head: [B, K, nb, bt, hd].
+
+    ``stack`` is [L, K, F, bt, hd] or [L, P, K, F, bt, hd], read as one
+    gather on its flat [L * (P *) K * F, bt, hd] view at rows
+    ``((layer * P + pool) * K + head) * F + frame``.  On a TPU v5e this 1-D
+    index compiles to a faster gather than a multi-dimensional one, which
+    the gather unpacks; slicing the layer's pool out first would make XLA
+    copy the whole pool before the gather reads it.
+
+    An unmapped block (-1) reads frame ``(b * nb + i) % F``, which the
+    attention masks out.  Sent all to frame 0, they read the same 4 KB
+    [bt, hd] block of each head again and again: a qwen3_14b decode step
+    with two thirds of its blocks unmapped took 14% longer on a TPU v5e."""
+    K, F = stack.shape[-4:-2]
+    filler = jnp.arange(frames.size).reshape(frames.shape) % F
+    frames = jnp.where(frames >= 0, frames, filler)
+    base = layer_idx
+    if pool is not None:
+        base = (layer_idx * stack.shape[1] + pool)[:, None, :]
+    heads = jnp.arange(K)[None, :, None]
+    flat = stack.reshape((-1,) + stack.shape[-2:])
+    return flat[(base * K + heads) * F + frames[:, None, :]]
+
+
 @jax.named_scope("kv_gather")
 def update_gather_plain(k_slabs: jax.Array, v_slabs: jax.Array,
                         k_new: jax.Array, v_new: jax.Array,
                         phys_blocks: jax.Array, positions: jax.Array,
                         block_tokens: int, fused_scope: bool = False
                         ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Single-pool path.  k_slabs [F, bt, K, hd]; k_new [B, K, hd].
+    """Single-pool path.  k_slabs [K, F, bt, hd]; k_new [B, K, hd].
+    Returns the written slabs and the gathered blocks [B, K, nb, bt, hd].
     fused_scope=True declares the update+gather VMEM-resident (it ships as
     the Pallas paged-attention kernel, which streams slabs per block)."""
-    import contextlib
     ctx = (jax.named_scope("vmem_paged_gather") if fused_scope
            else contextlib.nullcontext())
     with ctx:
-        bt = block_tokens
-        slot = positions % bt
-        blk = jnp.clip(positions // bt, 0, phys_blocks.shape[1] - 1)
-        frame = jnp.take_along_axis(phys_blocks, blk[:, None], axis=1)[:, 0]
-        valid = frame >= 0
-        frame = jnp.where(valid, frame, 0)
-        # per-row dynamic_update_slice instead of a batched scatter: XLA
-        # expands small scatters into whole-buffer gather+select rewrites,
-        # which would bill (and on CPU, actually move) the entire cache
-        # for a one-token write.  Rows whose current block is unmapped
-        # (-1 tables: inactive/padding rows) write the slab's own bytes
-        # back, so they can never corrupt frame 0.
-        def write(slabs, args):
-            f, s, val, ok = args
-            val = jnp.where(ok, val.astype(slabs.dtype), slabs[f, s])
-            return jax.lax.dynamic_update_slice(
-                slabs, val[None, None],
-                (f, s, jnp.zeros((), f.dtype), jnp.zeros((), f.dtype))), None
-
-        k_slabs, _ = jax.lax.scan(write, k_slabs, (frame, slot, k_new, valid))
-        v_slabs, _ = jax.lax.scan(write, v_slabs, (frame, slot, v_new, valid))
-        gather = jnp.where(phys_blocks >= 0, phys_blocks, 0)
-        return k_slabs, v_slabs, k_slabs[gather], v_slabs[gather]
-
-
-def _gather_layer(stack: jax.Array, layer_idx: jax.Array,
-                  frames: jax.Array) -> jax.Array:
-    """``stack[layer_idx][frames]`` as one gather on the [L * F, ...] view
-    of ``stack`` [L, F, ...], at flat rows ``layer_idx * F + frames``.  On
-    a TPU v5e this 1-D index compiles to a faster gather than
-    ``stack[layer_idx, frames]``, whose 2-D index the gather unpacks."""
-    L, F = stack.shape[:2]
-    return stack.reshape((L * F,) + stack.shape[2:])[layer_idx * F + frames]
+        frame, slot = _token_frames(phys_blocks, positions, block_tokens,
+                                    k_slabs.shape[1])
+        k_slabs = _write_rows(k_slabs, k_new, frame, slot)
+        v_slabs = _write_rows(v_slabs, v_new, frame, slot)
+        return (k_slabs, v_slabs,
+                _gather_layer(k_slabs[None], 0, phys_blocks),
+                _gather_layer(v_slabs[None], 0, phys_blocks))
 
 
 @jax.named_scope("kv_gather")
@@ -86,20 +158,19 @@ def gather_readonly(k_stack: jax.Array, v_stack: jax.Array,
                     layer_idx: jax.Array, phys_blocks: jax.Array,
                     fused_scope: bool = False
                     ) -> Tuple[jax.Array, jax.Array]:
-    """Read-only gather of one layer's live blocks from the stacked cache.
+    """Read-only gather of one layer's live blocks from the stacked cache:
+    [B, K, nb, bt, hd] each.
 
-    k_stack: [L, F, bt, K, hd] (or [L, P, F_local, ...] pooled).  Keeping
-    the cache read-only inside the layer scan is what lets XLA alias the
-    buffer through the loop — scan-carried *updated* slabs force a
-    whole-layer copy per iteration (and a full-cache double buffer on some
-    backends).  The new token's KV is appended to the attention outside
-    (see attn_decode_paged) and committed post-scan by commit_token_writes.
+    k_stack: [L, K, F, bt, hd] (or [L, P, K, F_local, bt, hd] pooled).
+    Keeping the cache read-only inside the layer scan is what lets XLA
+    alias the buffer through the loop — scan-carried *updated* slabs force
+    a whole-layer copy per iteration (and a full-cache double buffer on
+    some backends).  The new token's KV is appended to the attention
+    outside (see attn_decode_paged_ro) and committed post-scan by
+    commit_token_writes.
 
-    Each branch is one gather on the stack itself (``_gather_layer``):
-    slicing the layer's pool out first makes XLA copy the whole pool (in
-    HBM at long contexts) before the gather reads it.
+    Each branch is one gather on the stack itself (``_gather_layer``).
     """
-    import contextlib
     ctx = (jax.named_scope("vmem_paged_gather") if fused_scope
            else contextlib.nullcontext())
     pooled = k_stack.ndim == 6
@@ -108,27 +179,21 @@ def gather_readonly(k_stack: jax.Array, v_stack: jax.Array,
     data_ax = rules.lookup("blocks")
     with ctx:
         if not pooled:
-            gather = jnp.where(phys_blocks >= 0, phys_blocks, 0)
-            return (_gather_layer(k_stack, layer_idx, gather),
-                    _gather_layer(v_stack, layer_idx, gather))
+            return (_gather_layer(k_stack, layer_idx, phys_blocks),
+                    _gather_layer(v_stack, layer_idx, phys_blocks))
         if not _on_mesh(mesh, data_ax):
-            L, P_, F = k_stack.shape[:3]
-            pool_of = jnp.arange(phys_blocks.shape[0]) // max(
-                phys_blocks.shape[0] // P_, 1)
-            glob = jnp.where(phys_blocks >= 0,
-                             phys_blocks + pool_of[:, None] * F, 0)
-            ks = k_stack.reshape((L, P_ * F) + k_stack.shape[3:])
-            vs = v_stack.reshape((L, P_ * F) + v_stack.shape[3:])
-            return (_gather_layer(ks, layer_idx, glob),
-                    _gather_layer(vs, layer_idx, glob))
+            pool = jnp.broadcast_to(
+                _pool_of_rows(phys_blocks.shape[0], k_stack.shape[1])[:, None],
+                phys_blocks.shape)
+            return (_gather_layer(k_stack, layer_idx, phys_blocks, pool),
+                    _gather_layer(v_stack, layer_idx, phys_blocks, pool))
 
         hd_ax = rules.lookup("head_dim")
         kv_ax = rules.lookup("kv_heads")
-        stack_spec = P(None, data_ax, None, None, kv_ax, hd_ax)
-        out_spec = P(data_ax, None, None, kv_ax, hd_ax)
+        stack_spec = P(None, data_ax, kv_ax, None, None, hd_ax)
+        out_spec = P(data_ax, kv_ax, None, None, hd_ax)
 
-        def local(ks, vs, pb, li):
-            g = jnp.where(pb >= 0, pb, 0)
+        def local(ks, vs, g, li):
             return (_gather_layer(ks[:, 0], li, g),
                     _gather_layer(vs[:, 0], li, g))
 
@@ -139,34 +204,41 @@ def gather_readonly(k_stack: jax.Array, v_stack: jax.Array,
         return f(k_stack, v_stack, phys_blocks, layer_idx)
 
 
-def _commit_plain(k_stack, v_stack, k_new, v_new, frame, slot, valid=None):
-    """k_stack [L,F,bt,K,hd]; k_new [L,B,K,hd]; one DUS per row writes its
-    new token into every layer at once (the row's frame and slot are the
-    same in all layers), so the loop runs B times, not L * B: each of its
-    iterations is a handful of device operations.  ``valid`` [B] masks
-    inactive (padding) rows into write-backs of the slab's own bytes, so
-    unmapped rows never touch frame 0."""
-    B = k_new.shape[1]
-    if valid is None:
-        valid = jnp.ones((B,), bool)
-    zero = jnp.zeros((), frame.dtype)
+def _write_pools(write, k_stack: jax.Array, v_stack: jax.Array,
+                 k: jax.Array, v: jax.Array, *per_row: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """``write(stack, new, *per_row, pool)`` on the K and the V stack, each
+    [L, (P,) K, F, bt, hd]; ``new`` is [L, B, ..., K, hd] and each of
+    ``per_row`` has the batch axis first.  ``pool`` is None for a plain
+    stack, each row's pool for a pooled one, and on a mesh each shard
+    writes its own pool in shard_map."""
+    if k_stack.ndim == 5:
+        return (write(k_stack, k, *per_row, None),
+                write(v_stack, v, *per_row, None))
 
-    def write(stacks, args):
-        ks, vs = stacks
-        b, kv_, vv_ = args                       # kv_ [L, K, hd]
-        idx = (zero, frame[b], slot[b], zero, zero)
-        kv_ = jnp.where(valid[b], kv_.astype(ks.dtype),
-                        ks[:, frame[b], slot[b]])
-        vv_ = jnp.where(valid[b], vv_.astype(vs.dtype),
-                        vs[:, frame[b], slot[b]])
-        ks = jax.lax.dynamic_update_slice(ks, kv_[:, None, None], idx)
-        vs = jax.lax.dynamic_update_slice(vs, vv_[:, None, None], idx)
-        return (ks, vs), None
+    mesh = _mesh()
+    rules = current_rules()
+    data_ax = rules.lookup("blocks")
+    if not _on_mesh(mesh, data_ax):
+        pool = _pool_of_rows(k.shape[1], k_stack.shape[1])
+        return (write(k_stack, k, *per_row, pool),
+                write(v_stack, v, *per_row, pool))
 
-    (k_stack, v_stack), _ = jax.lax.scan(
-        write, (k_stack, v_stack),
-        (jnp.arange(B), jnp.swapaxes(k_new, 0, 1), jnp.swapaxes(v_new, 0, 1)))
-    return k_stack, v_stack
+    hd_ax = rules.lookup("head_dim")
+    kv_ax = rules.lookup("kv_heads")
+    stack_spec = P(None, data_ax, kv_ax, None, None, hd_ax)
+    new_spec = P(None, data_ax, *(None,) * (k.ndim - 4), kv_ax, hd_ax)
+    row_specs = tuple(P(data_ax, *(None,) * (a.ndim - 1)) for a in per_row)
+
+    def local(ks, vs, kn, vn, *rows):
+        pool = jnp.zeros(kn.shape[1], jnp.int32)   # this shard's one pool
+        return (write(ks, kn, *rows, pool), write(vs, vn, *rows, pool))
+
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(stack_spec, stack_spec, new_spec, new_spec)
+                      + row_specs,
+                      out_specs=(stack_spec, stack_spec), check_vma=False)
+    return f(k_stack, v_stack, k, v, *per_row)
 
 
 @jax.named_scope("kv_commit")
@@ -174,50 +246,31 @@ def commit_token_writes(k_stack: jax.Array, v_stack: jax.Array,
                         k_new: jax.Array, v_new: jax.Array,
                         phys_blocks: jax.Array, positions: jax.Array,
                         block_tokens: int) -> Tuple[jax.Array, jax.Array]:
-    """Write every layer's new-token KV into the stacked cache in one pass.
+    """Write every layer's new-token KV into the stacked cache after the
+    layer scan: one scatter per stack, of L * B * K single ``hd`` rows
+    (``_write_rows``); rows whose block is unmapped are dropped.
 
-    k_new/v_new: [L, B, K, hd] (collected scan outputs); traffic is
-    L*B*K*hd — the cache itself is aliased in place."""
-    L, B = k_new.shape[:2]
-    bt = block_tokens
-    slot = positions % bt
-    blk = jnp.clip(positions // bt, 0, phys_blocks.shape[1] - 1)
-    frame = jnp.take_along_axis(phys_blocks, blk[:, None], axis=1)[:, 0]
-    valid = frame >= 0
-    frame = jnp.where(valid, frame, 0)
-    pooled = k_stack.ndim == 6
-    if not pooled:
-        return _commit_plain(k_stack, v_stack, k_new, v_new, frame, slot,
-                             valid)
+    k_new/v_new: [L, B, K, hd] (collected scan outputs); positions [B];
+    traffic is L*B*K*hd — the cache itself is aliased in place."""
+    frame, slot = _token_frames(phys_blocks, positions, block_tokens,
+                                k_stack.shape[-3])
+    return _write_pools(_write_rows, k_stack, v_stack, k_new, v_new, frame,
+                        slot)
 
-    mesh = _mesh()
-    rules = current_rules()
-    data_ax = rules.lookup("blocks")
-    if not _on_mesh(mesh, data_ax):
-        P_, F = k_stack.shape[1:3]
-        pool_of = jnp.arange(B) // max(B // P_, 1)
-        gframe = frame + pool_of * F
-        ks = k_stack.reshape((L, P_ * F) + k_stack.shape[3:])
-        vs = v_stack.reshape((L, P_ * F) + v_stack.shape[3:])
-        ks, vs = _commit_plain(ks, vs, k_new, v_new, gframe, slot, valid)
-        return ks.reshape(k_stack.shape), vs.reshape(v_stack.shape)
 
-    hd_ax = rules.lookup("head_dim")
-    kv_ax = rules.lookup("kv_heads")
-    stack_spec = P(None, data_ax, None, None, kv_ax, hd_ax)
-    new_spec = P(None, data_ax, kv_ax, hd_ax)
-
-    def local(ks, vs, kn, vn, fr, sl, ok):
-        ks2 = ks[:, 0]
-        vs2 = vs[:, 0]
-        ks2, vs2 = _commit_plain(ks2, vs2, kn, vn, fr, sl, ok)
-        return ks2[:, None], vs2[:, None]
-
-    f = jax.shard_map(local, mesh=mesh,
-                      in_specs=(stack_spec, stack_spec, new_spec, new_spec,
-                                P(data_ax), P(data_ax), P(data_ax)),
-                      out_specs=(stack_spec, stack_spec), check_vma=False)
-    return f(k_stack, v_stack, k_new, v_new, frame, slot, valid)
+@jax.named_scope("kv_scatter")
+def scatter_prefill(k_stack: jax.Array, v_stack: jax.Array, k: jax.Array,
+                    v: jax.Array, phys_blocks: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """Write a prompt's KV, positions 0..S-1 of every row, for every layer
+    into the stacked cache after the layer scan: one scatter per stack of
+    whole [bt, hd] blocks (``_write_blocks``).  k/v [L, B, S, K, hd].
+    Blocks that are unmapped (-1: inactive/padding rows) are dropped —
+    never written to frame 0."""
+    nb = -(-k.shape[2] // k_stack.shape[-2])
+    frame = phys_blocks[:, :nb]
+    frame = jnp.where(frame >= 0, frame, k_stack.shape[-3])
+    return _write_pools(_write_blocks, k_stack, v_stack, k, v, frame)
 
 
 @jax.named_scope("kv_gather")
@@ -226,29 +279,30 @@ def update_gather_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
                          phys_blocks: jax.Array, positions: jax.Array,
                          block_tokens: int, fused_scope: bool = False
                          ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Pool-partitioned path.  k_slabs [Pools, F_local, bt, K, hd];
+    """Pool-partitioned path.  k_slabs [Pools, K, F_local, bt, hd];
     phys_blocks frame ids are LOCAL to each sequence's pool; the batch axis
     is sharded over 'data' in lockstep with the pool axis."""
     mesh = _mesh()
     rules = current_rules()
     data_ax = rules.lookup("blocks")  # pool axes
     if not _on_mesh(mesh, data_ax):
-        # no mesh (smoke tests): collapse pools and run the plain path
-        P_, F = k_slabs.shape[:2]
-        pool_of = jnp.arange(phys_blocks.shape[0]) // max(
-            phys_blocks.shape[0] // P_, 1)
-        glob = jnp.where(phys_blocks >= 0,
-                         phys_blocks + pool_of[:, None] * F, -1)
-        kf = k_slabs.reshape((P_ * F,) + k_slabs.shape[2:])
-        vf = v_slabs.reshape((P_ * F,) + v_slabs.shape[2:])
-        kf, vf, ka, va = update_gather_plain(kf, vf, k_new, v_new, glob,
-                                             positions, block_tokens,
-                                             fused_scope)
-        return (kf.reshape(k_slabs.shape), vf.reshape(v_slabs.shape), ka, va)
+        # no mesh (smoke tests): each row reads and writes its own pool
+        ctx = (jax.named_scope("vmem_paged_gather") if fused_scope
+               else contextlib.nullcontext())
+        with ctx:
+            frame, slot = _token_frames(phys_blocks, positions, block_tokens,
+                                        k_slabs.shape[2])
+            pool = _pool_of_rows(frame.shape[0], k_slabs.shape[0])
+            k_slabs = _write_rows(k_slabs, k_new, frame, slot, pool)
+            v_slabs = _write_rows(v_slabs, v_new, frame, slot, pool)
+            pools = jnp.broadcast_to(pool[:, None], phys_blocks.shape)
+            return (k_slabs, v_slabs,
+                    _gather_layer(k_slabs[None], 0, phys_blocks, pools),
+                    _gather_layer(v_slabs[None], 0, phys_blocks, pools))
 
     hd_ax = rules.lookup("head_dim")
     kv_ax = rules.lookup("kv_heads")
-    slab_spec = P(data_ax, None, None, kv_ax, hd_ax)
+    slab_spec = P(data_ax, kv_ax, None, None, hd_ax)
     new_spec = P(data_ax, kv_ax, hd_ax)
     tbl_spec = P(data_ax, None)
 
@@ -263,8 +317,8 @@ def update_gather_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
         in_specs=(slab_spec, slab_spec, new_spec, new_spec, tbl_spec,
                   P(data_ax)),
         out_specs=(slab_spec, slab_spec,
-                   P(data_ax, None, None, kv_ax, hd_ax),
-                   P(data_ax, None, None, kv_ax, hd_ax)),
+                   P(data_ax, kv_ax, None, None, hd_ax),
+                   P(data_ax, kv_ax, None, None, hd_ax)),
         check_vma=False)
     return f(k_slabs, v_slabs, k_new, v_new, phys_blocks, positions)
 
@@ -284,56 +338,54 @@ def decode_attention_sp(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
     softmax over its slice; partials combine with three scalar-sized
     collectives (max, sum, weighted-acc) instead of moving any KV.
 
-    q: [B,H,hd]; k/v_slabs: [P, F_local, bt, K, hd]; phys_blocks: [B, MB]
+    q: [B,H,hd]; k/v_slabs: [P, K, F_local, bt, hd]; phys_blocks: [B, MB]
     (frames local to the owning shard's pool); positions/seq_lens: [B].
     Returns (out [B,H,hd] f32, k_slabs, v_slabs).
     """
     mesh = _mesh()
     rules = current_rules()
     data_ax = rules.lookup("blocks")
-    hd_ax = rules.lookup("head_dim")
-    kv_ax = rules.lookup("kv_heads")
     B, H, hd = q.shape
     G = H // n_kv
     scale = hd ** -0.5
     NEG = -2.0 ** 30
+    bt = block_tokens
+    qg = q.reshape(B, n_kv, G, hd)
+
+    def scores_of(k_all, t, tables, pos, lens):
+        """Scores [B,K,G,T] over gathered blocks [B,K,nb,bt,hd] at token
+        indices ``t`` [T], and which of them a row may attend."""
+        k_all = k_all.reshape(B, n_kv, -1, hd)
+        s = jnp.einsum("bkgd,bktd->bkgt", qg, k_all,
+                       preferred_element_type=jnp.float32) * scale
+        ok = (t[None, :] < lens[:, None]) & jnp.repeat(tables >= 0, bt,
+                                                        axis=1)
+        if window is not None:
+            ok &= (pos[:, None] - t[None, :]) < window
+        return jnp.where(ok[:, None, None, :], s, NEG), ok
 
     def local(q, ks, vs, pb, pos, lens, shard_idx):
-        # ks/vs: [F_local, bt, K, hd]; pb: [B, MB_local] columns of my slice
-        bt = block_tokens
+        # ks/vs: [K, F_local, bt, hd]; pb: [B, MB_local] columns of my slice
         MBl = pb.shape[1]
         col0 = shard_idx * MBl                    # my first global column
         # write the new token's KV if its block lives in my slice
         blk = pos // bt
-        slot = pos % bt
         mine = (blk >= col0) & (blk < col0 + MBl)
-        local_col = jnp.clip(blk - col0, 0, MBl - 1)
-        frame = jnp.take_along_axis(pb, local_col[:, None], axis=1)[:, 0]
-        frame_w = jnp.where(mine & (frame >= 0), frame, 0)
-        k_upd = jnp.where(mine[:, None, None], k_new.astype(ks.dtype),
-                          ks[frame_w, slot])
-        v_upd = jnp.where(mine[:, None, None], v_new.astype(vs.dtype),
-                          vs[frame_w, slot])
-        ks = ks.at[frame_w, slot].set(k_upd)
-        vs = vs.at[frame_w, slot].set(v_upd)
+        frame, slot = _token_frames(pb, pos - col0 * bt, bt, ks.shape[1])
+        frame = jnp.where(mine, frame, ks.shape[1])
+        ks = _write_rows(ks, k_new, frame, slot)
+        vs = _write_rows(vs, v_new, frame, slot)
         # gather my slice and compute the partial softmax
-        gather = jnp.where(pb >= 0, pb, 0)
-        k_all = ks[gather].reshape(B, MBl * bt, n_kv, hd)
-        v_all = vs[gather].reshape(B, MBl * bt, n_kv, hd)
-        qg = q.reshape(B, n_kv, G, hd)
+        k_all = _gather_layer(ks[None], 0, pb)
+        v_all = _gather_layer(vs[None], 0, pb).reshape(B, n_kv, -1, hd)
         with jax.named_scope("vmem_paged_attn_sp"):
-            s = jnp.einsum("bkgd,btkd->bkgt", qg, k_all,
-                           preferred_element_type=jnp.float32) * scale
-            t = col0 * bt + jnp.arange(MBl * bt)
-            ok = (t[None, :] < lens[:, None]) & jnp.repeat(pb >= 0, bt, axis=1)
-            if window is not None:
-                ok &= (pos[:, None] - t[None, :]) < window
-            s = jnp.where(ok[:, None, None, :], s, NEG)
+            s, ok = scores_of(k_all, col0 * bt + jnp.arange(MBl * bt), pb,
+                              pos, lens)
             m = jnp.max(s, axis=-1)                      # [B,K,G]
             p = jnp.exp(s - m[..., None])
             p = jnp.where(ok[:, None, None, :], p, 0.0)
             l = jnp.sum(p, axis=-1)
-            acc = jnp.einsum("bkgt,btkd->bkgd", p,
+            acc = jnp.einsum("bkgt,bktd->bkgd", p,
                              v_all.astype(jnp.float32))
         # combine partials across shards
         from jax import lax
@@ -345,33 +397,24 @@ def decode_attention_sp(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
         return out, ks[None], vs[None]
 
     if not _on_mesh(mesh, data_ax):
-        # single-device fallback: flatten pools and reuse the plain path
-        P_, F = k_slabs.shape[:2]
+        # single device: column c's frames live in pool c // (MB / P)
+        n_pools, F = k_slabs.shape[0], k_slabs.shape[2]
         MB = phys_blocks.shape[1]
-        MBl = MB // P_
-        col_shard = jnp.arange(MB) // MBl
-        glob = jnp.where(phys_blocks >= 0,
-                         phys_blocks + col_shard[None, :] * F, -1)
-        kf = k_slabs.reshape((P_ * F,) + k_slabs.shape[2:])
-        vf = v_slabs.reshape((P_ * F,) + v_slabs.shape[2:])
-        kf, vf, k_all, v_all = update_gather_plain(
-            kf, vf, k_new, v_new, glob, positions, block_tokens)
-        bt = block_tokens
-        k_all = k_all.reshape(B, MB * bt, n_kv, hd)
-        v_all = v_all.reshape(B, MB * bt, n_kv, hd)
-        qg = q.reshape(B, n_kv, G, hd)
-        s = jnp.einsum("bkgd,btkd->bkgt", qg, k_all,
-                       preferred_element_type=jnp.float32) * scale
-        t = jnp.arange(MB * bt)
-        ok = (t[None, :] < seq_lens[:, None]) & jnp.repeat(
-            phys_blocks >= 0, bt, axis=1)
-        if window is not None:
-            ok &= (positions[:, None] - t[None, :]) < window
-        s = jnp.where(ok[:, None, None, :], s, NEG)
+        col_pool = jnp.arange(MB) // (MB // n_pools)
+        frame, slot = _token_frames(phys_blocks, positions, bt, F)
+        pool = col_pool[jnp.clip(positions // bt, 0, MB - 1)]
+        k_slabs = _write_rows(k_slabs, k_new, frame, slot, pool)
+        v_slabs = _write_rows(v_slabs, v_new, frame, slot, pool)
+        pools = jnp.broadcast_to(col_pool[None, :], phys_blocks.shape)
+        k_all = _gather_layer(k_slabs[None], 0, phys_blocks, pools)
+        v_all = _gather_layer(v_slabs[None], 0, phys_blocks, pools)
+        s, _ = scores_of(k_all, jnp.arange(MB * bt), phys_blocks, positions,
+                         seq_lens)
         p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bkgt,btkd->bkgd", p.astype(v_all.dtype), v_all,
-                         preferred_element_type=jnp.float32).reshape(B, H, hd)
-        return out, kf.reshape(k_slabs.shape), vf.reshape(v_slabs.shape)
+        out = jnp.einsum("bkgt,bktd->bkgd", p.astype(v_all.dtype),
+                         v_all.reshape(B, n_kv, -1, hd),
+                         preferred_element_type=jnp.float32)
+        return out.reshape(B, H, hd), k_slabs, v_slabs
 
     # SP layout: slabs replicated over 'model' (the per-device share comes
     # from the 'data' split of the sequence), q replicated — the partial
@@ -389,65 +432,3 @@ def decode_attention_sp(q: jax.Array, k_slabs: jax.Array, v_slabs: jax.Array,
         out_specs=(P(), slab_spec, slab_spec),
         check_vma=False)
     return f(q, k_slabs, v_slabs, phys_blocks, positions, seq_lens)
-
-
-@jax.named_scope("kv_scatter")
-def scatter_prefill_plain(k_slabs: jax.Array, v_slabs: jax.Array,
-                          k: jax.Array, v: jax.Array,
-                          phys_blocks: jax.Array, positions: jax.Array,
-                          block_tokens: int) -> Tuple[jax.Array, jax.Array]:
-    """Scatter a full prompt's KV into slabs.  k [B,S,K,hd]; positions
-    [B,S].  Tokens whose block is unmapped (-1: inactive/padding rows) are
-    redirected out of bounds, which JAX scatter drops — never frame 0."""
-    B, S = positions.shape
-    bt = block_tokens
-    blk = jnp.clip(positions // bt, 0, phys_blocks.shape[1] - 1)
-    frame = jnp.take_along_axis(phys_blocks, blk, axis=1)
-    frame = jnp.where(frame >= 0, frame, k_slabs.shape[0])
-    slot = positions % bt
-    k_slabs = k_slabs.at[frame.reshape(-1), slot.reshape(-1)].set(
-        k.reshape((B * S,) + k.shape[2:]).astype(k_slabs.dtype),
-        mode="drop")
-    v_slabs = v_slabs.at[frame.reshape(-1), slot.reshape(-1)].set(
-        v.reshape((B * S,) + v.shape[2:]).astype(v_slabs.dtype),
-        mode="drop")
-    return k_slabs, v_slabs
-
-
-@jax.named_scope("kv_scatter")
-def scatter_prefill_pooled(k_slabs: jax.Array, v_slabs: jax.Array,
-                           k: jax.Array, v: jax.Array,
-                           phys_blocks: jax.Array, positions: jax.Array,
-                           block_tokens: int) -> Tuple[jax.Array, jax.Array]:
-    """Pool-partitioned prefill scatter (frames local to each pool)."""
-    mesh = _mesh()
-    rules = current_rules()
-    data_ax = rules.lookup("blocks")
-    if not _on_mesh(mesh, data_ax):
-        P_, F = k_slabs.shape[:2]
-        pool_of = jnp.arange(phys_blocks.shape[0]) // max(
-            phys_blocks.shape[0] // P_, 1)
-        glob = jnp.where(phys_blocks >= 0,
-                         phys_blocks + pool_of[:, None] * F, -1)
-        kf = k_slabs.reshape((P_ * F,) + k_slabs.shape[2:])
-        vf = v_slabs.reshape((P_ * F,) + v_slabs.shape[2:])
-        kf, vf = scatter_prefill_plain(kf, vf, k, v, glob, positions,
-                                       block_tokens)
-        return kf.reshape(k_slabs.shape), vf.reshape(v_slabs.shape)
-
-    hd_ax = rules.lookup("head_dim")
-    kv_ax = rules.lookup("kv_heads")
-    slab_spec = P(data_ax, None, None, kv_ax, hd_ax)
-    kv_spec = P(data_ax, None, kv_ax, hd_ax)
-
-    def local(ks, vs, kn, vn, pb, pos):
-        ks, vs = scatter_prefill_plain(ks[0], vs[0], kn, vn, pb, pos,
-                                       block_tokens)
-        return ks[None], vs[None]
-
-    f = jax.shard_map(local, mesh=mesh,
-                      in_specs=(slab_spec, slab_spec, kv_spec, kv_spec,
-                                P(data_ax, None), P(data_ax, None)),
-                      out_specs=(slab_spec, slab_spec),
-                      check_vma=False)
-    return f(k_slabs, v_slabs, k, v, phys_blocks, positions)

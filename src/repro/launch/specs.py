@@ -375,10 +375,10 @@ def state_shardings(cfg: ModelConfig, state_shapes, mesh: Mesh,
     def shard_cache(leaf_path, leaf):
         name = str(leaf_path[-1].key) if hasattr(leaf_path[-1], "key") else ""
         nd = len(leaf.shape)
-        if name in ("k_slabs", "v_slabs") and nd == 6:
-            spec = P(None, blocks_ax, None, None, kv_ax, hd_ax)
-        elif name in ("k_slabs", "v_slabs"):
-            spec = P(None, blocks_ax, None, kv_ax, hd_ax)
+        if name in ("k_slabs", "v_slabs") and nd == 6:   # [L,P,K,F,bt,hd]
+            spec = P(None, blocks_ax, kv_ax, None, None, hd_ax)
+        elif name in ("k_slabs", "v_slabs"):             # [L,K,F,bt,hd]
+            spec = P(None, kv_ax, blocks_ax, None, hd_ax)
         elif name in ("ring_k", "ring_v"):
             spec = P(None, batch_ax, None, kv_ax, hd_ax)
         elif name in ("cross_k", "cross_v"):

@@ -10,7 +10,7 @@ paper's address translation.
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +73,11 @@ def _gqa_scores(cfg: ModelConfig, q: jax.Array, k: jax.Array) -> jax.Array:
     # materialize a full-precision copy of the whole cache
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
                         preferred_element_type=jnp.float32)
+    return _scaled(cfg, scores, hd)
+
+
+def _scaled(cfg: ModelConfig, scores: jax.Array, hd: int) -> jax.Array:
+    """Raw q.k scores scaled by 1/sqrt(hd), then soft-capped."""
     scores *= hd ** -0.5
     if cfg.attn_logit_softcap:
         c = cfg.attn_logit_softcap
@@ -133,6 +138,60 @@ def attn_forward(cfg: ModelConfig, p: Dict[str, jax.Array], x: jax.Array,
     return constrain(out, "batch", "act_seq", None)
 
 
+def _attend_blocks(cfg: ModelConfig, q: jax.Array, k_all: jax.Array,
+                   v_all: jax.Array, valid: jax.Array,
+                   new: Optional[Tuple[jax.Array, jax.Array]] = None
+                   ) -> jax.Array:
+    """One query token per row over its gathered KV blocks, heads-major.
+
+    q [B, H, hd]; k_all, v_all [B, K, nb, bt, hd] as ``kv_gather`` lays
+    them out; valid [B, nb * bt]; ``new``: (k, v) [B, K, hd], one more
+    column every row attends.  The blocks' (nb, bt) axes merge into one
+    token axis, which moves no data, and no reshape moves a head.
+    Returns [B, H * hd] float32."""
+    B, H, hd = q.shape
+    K = k_all.shape[1]
+    k_all = k_all.reshape(B, K, -1, hd)
+    v_all = v_all.reshape(B, K, -1, hd)
+    qg = q.reshape(B, K, H // K, hd)
+    # bf16 operands with f32 accumulation (MXU numerics): converting k to
+    # f32 would let XLA hoist the convert over the KV gather
+    scores = _scaled(cfg, jnp.einsum("bkgd,bktd->bkgt", qg, k_all,
+                                     preferred_element_type=jnp.float32), hd)
+    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
+    m = jnp.max(scores, axis=-1)                            # [B,K,G]
+    if new is not None:
+        s_new = _scaled(cfg, jnp.einsum("bkgd,bkd->bkg", qg, new[0],
+                                        preferred_element_type=jnp.float32),
+                        hd)
+        m = jnp.maximum(m, s_new)
+        p_new = jnp.exp(s_new - m)
+    p = jnp.exp(scores - m[..., None])
+    total = jnp.sum(p, axis=-1)
+    if new is not None:
+        total = total + p_new
+    out = jnp.einsum("bkgt,bktd->bkgd",
+                     (p / total[..., None]).astype(v_all.dtype), v_all,
+                     preferred_element_type=jnp.float32)
+    if new is not None:
+        p_new = (p_new / total).astype(new[1].dtype)
+        out = out + jnp.einsum("bkg,bkd->bkgd", p_new, new[1],
+                               preferred_element_type=jnp.float32)
+    return out.reshape(B, H * hd)
+
+
+def _block_valid(phys_blocks: jax.Array, lens: jax.Array, bt: int,
+                 positions: jax.Array, window: Optional[int]) -> jax.Array:
+    """[B, nb * bt]: which tokens of a row's gathered blocks it attends:
+    before ``lens``, in a mapped block, inside ``window``."""
+    t = jnp.arange(phys_blocks.shape[1] * bt)
+    valid = t[None, :] < lens[:, None]
+    valid &= (phys_blocks >= 0).repeat(bt, axis=1)
+    if window is not None:
+        valid &= (positions[:, None] - t[None, :]) < window
+    return valid
+
+
 def attn_decode_paged_ro(cfg: ModelConfig, p: Dict[str, jax.Array],
                          x: jax.Array, positions: jax.Array,
                          k_stack: jax.Array, v_stack: jax.Array,
@@ -149,42 +208,18 @@ def attn_decode_paged_ro(cfg: ModelConfig, p: Dict[str, jax.Array],
     Returns (attn_out [B,1,D], k_new [B,K,hd], v_new [B,K,hd]).
     """
     from ..kvcache.gather import gather_readonly
-    B = x.shape[0]
-    hd = cfg.resolved_head_dim
-    K = cfg.n_kv_heads
-    bt = k_stack.shape[-3]
+    bt = k_stack.shape[-2]
     q, k_new, v_new = _qkv_decode(cfg, p, x, positions, rope_theta)
     k_all, v_all = gather_readonly(k_stack, v_stack, layer_idx, phys_blocks,
                                    fused_scope)
-    nb = phys_blocks.shape[1]
-    k_all = k_all.reshape(B, nb * bt, K, hd)
-    v_all = v_all.reshape(B, nb * bt, K, hd)
+    k_new, v_new = k_new[:, 0], v_new[:, 0]
     with jax.named_scope("attn"), jax.named_scope("vmem_paged_attn"):
-        scores = _gqa_scores(cfg, q, k_all)           # [B,K,G,1,T]
-        s_new = _gqa_scores(cfg, q, k_new)            # [B,K,G,1,1]
-        t = jnp.arange(nb * bt)
-        valid = t[None, :] < positions[:, None]       # strictly old tokens
-        valid &= (phys_blocks >= 0).repeat(bt, axis=1)
-        if window is not None:
-            valid &= (positions[:, None] - t[None, :]) < window
-        scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
-        full = jnp.concatenate([scores, s_new], axis=-1)
-        probs = jax.nn.softmax(full, axis=-1)
-        p_old, p_new = probs[..., :-1], probs[..., -1:]
-        out = jnp.einsum("bkgqs,bskd->bqkgd", p_old.astype(v_all.dtype),
-                         v_all, preferred_element_type=jnp.float32)
-        out = out + jnp.einsum("bkgqs,bskd->bqkgd",
-                               p_new.astype(v_new.dtype), v_new,
-                               preferred_element_type=jnp.float32)
-        out = out.reshape(B, 1, cfg.n_heads * hd).astype(cfg.dtype)
-        out = out @ p["wo"].astype(cfg.dtype)
-    return (constrain(out, "batch", None, None), k_new[:, 0], v_new[:, 0])
-
-
-class PagedKV(NamedTuple):
-    """Paged KV slabs for one layer group (leading layer axis for scan)."""
-    k: jax.Array   # [L, n_blocks, block_tokens, kv_heads, head_dim]
-    v: jax.Array   # [L, n_blocks, block_tokens, kv_heads, head_dim]
+        # strictly old tokens; the new one is the extra column
+        valid = _block_valid(phys_blocks, positions, bt, positions, window)
+        out = _attend_blocks(cfg, q[:, 0], k_all, v_all, valid,
+                             (k_new, v_new))
+        out = out[:, None].astype(cfg.dtype) @ p["wo"].astype(cfg.dtype)
+    return constrain(out, "batch", None, None), k_new, v_new
 
 
 def attn_decode_paged(cfg: ModelConfig, p: Dict[str, jax.Array],
@@ -197,9 +232,9 @@ def attn_decode_paged(cfg: ModelConfig, p: Dict[str, jax.Array],
     """One decode step (one new token per sequence) with paged KV.
 
     x: [B, 1, D]; positions: [B]; kv: (k_slabs, v_slabs) for THIS layer,
-    each [n_blocks, bt, K, hd]; phys_blocks: [B, max_blocks] physical frame
-    ids from the block-table translation (-1 = absent); seq_lens: [B]
-    length INCLUDING the new token.
+    each [K, n_blocks, bt, hd] (pooled [P, K, F_local, bt, hd]);
+    phys_blocks: [B, max_blocks] physical frame ids from the block-table
+    translation (-1 = absent); seq_lens: [B] length INCLUDING the new token.
     Returns (attn_out [B,1,D], updated slabs).
     """
     from ..kvcache.gather import (decode_attention_sp, update_gather_plain,
@@ -207,7 +242,7 @@ def attn_decode_paged(cfg: ModelConfig, p: Dict[str, jax.Array],
     B = x.shape[0]
     hd = cfg.resolved_head_dim
     K = cfg.n_kv_heads
-    bt = kv[0].shape[-3]
+    bt = kv[0].shape[-2]
     q, k_new, v_new = _qkv_decode(cfg, p, x, positions, rope_theta)
     if sp:
         # sequence-parallel long-context decode (flash-decoding combine)
@@ -244,20 +279,12 @@ def attn_decode_paged(cfg: ModelConfig, p: Dict[str, jax.Array],
                                             v_new[:, 0], phys_blocks,
                                             positions, bt,
                                             kernel == "fused_ref")
-        nb = phys_blocks.shape[1]
-        k_all = k_all.reshape(B, nb * bt, K, hd)
-        v_all = v_all.reshape(B, nb * bt, K, hd)
         # scores/softmax ship as the Pallas paged-attention kernel on TPU
         with jax.named_scope("attn"), jax.named_scope("vmem_paged_attn"):
-            scores = _gqa_scores(cfg, q, k_all)    # [B,K,G,1,T]
-            t = jnp.arange(nb * bt)
-            valid = (t[None, :] < seq_lens[:, None])
-            valid &= (phys_blocks >= 0).repeat(bt, axis=1)
-            if window is not None:
-                valid &= (positions[:, None] - t[None, :]) < window
-            scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = _gqa_out(cfg, probs, v_all, p)   # [B,1,D]
+            valid = _block_valid(phys_blocks, seq_lens, bt, positions,
+                                 window)
+            out = _attend_blocks(cfg, q[:, 0], k_all, v_all, valid)
+            out = out[:, None].astype(cfg.dtype) @ p["wo"].astype(cfg.dtype)
     return constrain(out, "batch", None, None), (k_slabs, v_slabs)
 
 

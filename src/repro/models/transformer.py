@@ -252,14 +252,15 @@ def init_decode_state(cfg: ModelConfig, batch: int, n_blocks: int,
     dtype = dtype or cfg.dtype
     hd, K = cfg.resolved_head_dim, cfg.n_kv_heads
     bt = cfg.kv_block_tokens
-    slab_dims = ((n_pools, n_blocks // n_pools) if n_pools > 1
-                 else (n_blocks,))
+    # heads before frames: [L, (P,) K, F, bt, hd] (kvcache.gather)
+    slab_dims = ((n_pools, K, n_blocks // n_pools) if n_pools > 1
+                 else (K, n_blocks))
     caches: List[Dict[str, jax.Array]] = []
     for g in layer_groups(cfg):
         L = g.n_layers
         if g.kind in ("attn", "dec_attn") and g.window is None:
-            c = {"k_slabs": jnp.zeros((L,) + slab_dims + (bt, K, hd), dtype),
-                 "v_slabs": jnp.zeros((L,) + slab_dims + (bt, K, hd), dtype)}
+            c = {"k_slabs": jnp.zeros((L,) + slab_dims + (bt, hd), dtype),
+                 "v_slabs": jnp.zeros((L,) + slab_dims + (bt, hd), dtype)}
             if g.kind == "dec_attn":
                 c["cross_k"] = jnp.zeros((L, batch, enc_len, K, hd), dtype)
                 c["cross_v"] = jnp.zeros((L, batch, enc_len, K, hd), dtype)
@@ -502,36 +503,31 @@ def _prefill_group(cfg, g, gp, cache, x, positions, phys_blocks):
     from .attention import _project_qkv
     from .common import apply_rope
     B, S, _ = x.shape
-    bt = cfg.kv_block_tokens
 
     if g.kind == "attn" and g.window is None:
-        from ..kvcache.gather import (scatter_prefill_plain,
-                                      scatter_prefill_pooled)
+        from ..kvcache.gather import scatter_prefill
 
         gp_scan, experts = _experts_out_of_scan(g, gp)
 
         def body(carry, xs):
             x = carry
-            lp, li, ks, vs = xs
+            lp, li = xs
             with jax.named_scope("attn_qkv"):
                 h = apply_norm(cfg, x, lp["norm1"])
             a = attn_forward(cfg, lp["attn"], h, positions, window=None,
                              rope_theta=g.rope_theta)
-            # scatter this layer's K/V into the paged slabs (pool-local)
+            # this layer's K/V, written into the paged cache after the scan
             with jax.named_scope("attn_qkv"):
                 q, k, v = _project_qkv(cfg, lp["attn"], h, h)
                 if cfg.use_rope:
                     k = apply_rope(k, positions, g.rope_theta)
-            scatter = (scatter_prefill_pooled if ks.ndim == 5
-                       else scatter_prefill_plain)
-            ks, vs = scatter(ks, vs, k, v, phys_blocks, positions, bt)
             with jax.named_scope("attn"):
                 x = x + a
-            return _ffn_block(cfg, g, lp, x, experts, li), (ks, vs)
+            return _ffn_block(cfg, g, lp, x, experts, li), (k, v)
 
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (gp_scan, jnp.arange(g.n_layers), cache["k_slabs"],
-                      cache["v_slabs"]))
+        x, (k, v) = jax.lax.scan(body, x, (gp_scan, jnp.arange(g.n_layers)))
+        ks, vs = scatter_prefill(cache["k_slabs"], cache["v_slabs"], k, v,
+                                 phys_blocks)
         return x, dict(cache, k_slabs=ks, v_slabs=vs)
 
     # other kinds: run the layer forward AND capture its decode state inside
@@ -597,7 +593,6 @@ def prefill_encdec(cfg: ModelConfig, params: PyTree, enc_feats: jax.Array,
     from .attention import _project_qkv
     B, Se, _ = enc_feats.shape
     Sd = dec_tokens.shape[1]
-    bt = cfg.kv_block_tokens
     enc_g, dec_g = layer_groups(cfg)
     enc_pos = jnp.broadcast_to(jnp.arange(Se)[None, :], (B, Se))
     x = enc_feats.astype(cfg.dtype) + _sinusoids(Se, cfg.d_model)[None]
@@ -625,18 +620,14 @@ def prefill_encdec(cfg: ModelConfig, params: PyTree, enc_feats: jax.Array,
     y = params["dec_embedding"].astype(cfg.dtype)[dec_tokens]
     y = y + params["dec_pos"].astype(cfg.dtype)[:Sd][None]
     dec_pos = jnp.broadcast_to(jnp.arange(Sd)[None, :], (B, Sd))
-    from ..kvcache.gather import scatter_prefill_plain, scatter_prefill_pooled
+    from ..kvcache.gather import scatter_prefill
 
-    def body(carry, xs):
+    def body(carry, lp):
         yv = carry
-        lp, ks, vs = xs
         h = apply_norm(cfg, yv, lp["norm1"])
         a = attn_forward(cfg, lp["attn"], h, dec_pos, window=None,
                          rope_theta=dec_g.rope_theta)
         q, k, v = _project_qkv(cfg, lp["attn"], h, h)
-        scatter = (scatter_prefill_pooled if ks.ndim == 5
-                   else scatter_prefill_plain)
-        ks, vs = scatter(ks, vs, k, v, phys_blocks, dec_pos, bt)
         yv = yv + a
         h = apply_norm(cfg, yv, lp["norm_cross"])
         a = attn_forward(cfg, lp["cross"], h, dec_pos, window=None,
@@ -644,10 +635,11 @@ def prefill_encdec(cfg: ModelConfig, params: PyTree, enc_feats: jax.Array,
                          kv_x=enc_out)
         yv = yv + a
         h = apply_norm(cfg, yv, lp["norm2"])
-        return yv + ffn_forward(cfg, lp["ffn"], h), (ks, vs)
+        return yv + ffn_forward(cfg, lp["ffn"], h), (k, v)
 
-    y, (ks, vs) = jax.lax.scan(
-        body, y, (dp, dec_cache["k_slabs"], dec_cache["v_slabs"]))
+    y, (k, v) = jax.lax.scan(body, y, dp)
+    ks, vs = scatter_prefill(dec_cache["k_slabs"], dec_cache["v_slabs"], k,
+                             v, phys_blocks)
     y = apply_norm(cfg, y, params["final_norm"])
     head = params.get("lm_head", params["dec_embedding"].T)
     logits = (y[:, -1] @ head.astype(cfg.dtype))

@@ -139,7 +139,8 @@ def test_gather_readonly_on_pod_mesh_matches_slice_then_gather():
     """``gather_readonly``'s shard_map branch on a (pod=4) mesh: each pool
     lies on its own devices, and every layer's gather reads bit for bit
     what slicing the layer's pool out of the stack and gathering the row's
-    frames (-1 entries clamped to its pool's frame 0) would."""
+    frames would.  An unmapped (-1) block i of a shard's row b reads frame
+    (b * nb + i) % F of that shard's pool, b counted within the shard."""
     out = run_in_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -148,7 +149,7 @@ def test_gather_readonly_on_pod_mesh_matches_slice_then_gather():
         from repro.launch.mesh import make_mesh
         L, PODS, F, BT, K, HD, B, MB = 3, 4, 6, 4, 2, 8, 8, 5
         rng = np.random.default_rng(0)
-        shape = (L, PODS, F, BT, K, HD)
+        shape = (L, PODS, K, F, BT, HD)
         k_np = rng.normal(size=shape).astype(np.float32)
         v_np = rng.normal(size=shape).astype(np.float32)
         tbl = rng.integers(0, F, (B, MB)).astype(np.int32)
@@ -157,7 +158,7 @@ def test_gather_readonly_on_pod_mesh_matches_slice_then_gather():
         tbl[6] = -1
         mesh = make_mesh((PODS, 1, 2), ("pod", "data", "model"))
         on = lambda *s: NamedSharding(mesh, P(*s))
-        stack_on = on(None, ("pod", "data"), None, None, "model", None)
+        stack_on = on(None, ("pod", "data"), "model", None, None, None)
         with use_rules(MULTI_POD_RULES), jax.set_mesh(mesh):
             ks = jax.device_put(jnp.asarray(k_np, jnp.bfloat16), stack_on)
             vs = jax.device_put(jnp.asarray(v_np, jnp.bfloat16), stack_on)
@@ -170,12 +171,17 @@ def test_gather_readonly_on_pod_mesh_matches_slice_then_gather():
         assert len(ks.sharding.device_set) == 8
         ka, va = np.asarray(ka), np.asarray(va)
         k_ref, v_ref = np.asarray(ks), np.asarray(vs)
-        frames = np.where(tbl >= 0, tbl, 0)
+        local_row = np.arange(B) % (B // PODS)
+        filler = (local_row[:, None] * MB + np.arange(MB)) % F
+        frames = np.where(tbl >= 0, tbl, filler)
         pool = np.arange(B) // (B // PODS)
         for li in range(L):
-            want_k = k_ref[li][pool[:, None], frames]
-            want_v = v_ref[li][pool[:, None], frames]
-            assert ka[li].shape == (B, MB, BT, K, HD)
+            # [B, MB, K, BT, HD] -> [B, K, MB, BT, HD]
+            want_k = np.ascontiguousarray(k_ref[li][pool[:, None], :, frames]
+                                          .transpose(0, 2, 1, 3, 4))
+            want_v = np.ascontiguousarray(v_ref[li][pool[:, None], :, frames]
+                                          .transpose(0, 2, 1, 3, 4))
+            assert ka[li].shape == (B, K, MB, BT, HD)
             assert ka[li].tobytes() == want_k.tobytes(), li
             assert va[li].tobytes() == want_v.tobytes(), li
         print("pod-gather-ok")

@@ -36,8 +36,8 @@ def _tables(B, MB, bt, N):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_matches_ref(B, H, K, hd, bt, MB, N, window, dtype):
     q = jnp.asarray(RNG.standard_normal((B, H, hd)), dtype)
-    ks = jnp.asarray(RNG.standard_normal((N, bt, K, hd)), dtype)
-    vs = jnp.asarray(RNG.standard_normal((N, bt, K, hd)), dtype)
+    ks = jnp.asarray(RNG.standard_normal((K, N, bt, hd)), dtype)
+    vs = jnp.asarray(RNG.standard_normal((K, N, bt, hd)), dtype)
     tables, lens = _tables(B, MB, bt, N)
     out = paged_attention(q, ks, vs, tables, lens, window=window)
     ref = paged_attention_ref(q, ks, vs, tables, lens, window=window)

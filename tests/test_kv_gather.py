@@ -1,8 +1,10 @@
 """``gather_readonly`` reads a layer's live blocks straight from the stacked
-KV cache.  Its values must be bit for bit those of slicing the layer's pool
-out of the stack, clamping -1 table entries to frame 0 and gathering the
-frames.  The layer index is traced, as in the decode step's scan over
-layers."""
+KV cache, laid out heads before frames ([L, K, F, bt, hd], pooled [L, P,
+K, F_local, bt, hd]).  Its values must be bit for bit those of slicing the
+layer's pool out of the stack and gathering every head's frames, [B, K,
+nb, bt, hd], where an unmapped (-1) block i of row b reads frame (b * nb
++ i) % F, so that no frame is read once for every unmapped block.  The
+layer index is traced, as in the decode step's scan over layers."""
 from __future__ import annotations
 
 import jax
@@ -38,7 +40,7 @@ def _scan_gather(k_stack, v_stack, tables):
 @pytest.mark.parametrize("pools", [0, 2], ids=["plain", "pooled"])
 def test_gather_readonly_matches_slice_then_gather(pools, dtype):
     rng = np.random.default_rng(pools)
-    shape = (L, F, BT, K, HD) if pools == 0 else (L, pools, F, BT, K, HD)
+    shape = (L, K, F, BT, HD) if pools == 0 else (L, pools, K, F, BT, HD)
     k_np = rng.normal(size=shape).astype(np.float32)
     v_np = rng.normal(size=shape).astype(np.float32)
     k_stack = jnp.asarray(k_np, dtype)
@@ -46,15 +48,18 @@ def test_gather_readonly_matches_slice_then_gather(pools, dtype):
     tables = _tables(rng, F)
     ka, va = _scan_gather(k_stack, v_stack, jnp.asarray(tables))
 
-    # reference: the layer's pool sliced out, pools laid end to end, each
-    # row reading its own pool (row b lives in pool b // (B / pools))
-    offset = (np.zeros(B, np.int32) if pools == 0
-              else np.arange(B) // (B // pools) * F)
-    frames = np.where(tables >= 0, tables + offset[:, None], 0)
-    k_ref = np.asarray(k_stack).reshape((L, -1, BT, K, HD))
-    v_ref = np.asarray(v_stack).reshape((L, -1, BT, K, HD))
+    # reference: the layer's pool sliced out, each row reading its own
+    # pool (row b lives in pool b // (B / pools)), every head's frames
+    filler = np.arange(B * MB).reshape(B, MB) % F
+    frames = np.where(tables >= 0, tables, filler)
+    k_ref, v_ref = np.asarray(k_stack), np.asarray(v_stack)
+    if pools == 0:
+        k_ref, v_ref = k_ref[:, None], v_ref[:, None]
+    pool = np.arange(B) // (B // max(pools, 1))
     for li in range(L):
-        want_k, want_v = k_ref[li][frames], v_ref[li][frames]
-        assert ka[li].shape == (B, MB, BT, K, HD)
-        assert ka[li].tobytes() == want_k.tobytes(), li
-        assert va[li].tobytes() == want_v.tobytes(), li
+        # [B, MB, K, BT, HD] -> [B, K, MB, BT, HD]
+        want_k = k_ref[li][pool[:, None], :, frames].transpose(0, 2, 1, 3, 4)
+        want_v = v_ref[li][pool[:, None], :, frames].transpose(0, 2, 1, 3, 4)
+        assert ka[li].shape == (B, K, MB, BT, HD)
+        assert ka[li].tobytes() == np.ascontiguousarray(want_k).tobytes(), li
+        assert va[li].tobytes() == np.ascontiguousarray(want_v).tobytes(), li

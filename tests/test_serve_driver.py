@@ -60,14 +60,14 @@ def test_padding_rows_never_write_device_kv():
     unmapped (-1) must leave the KV slabs byte-identical — the old clamp
     redirected their writes into frame 0, corrupting whichever live
     sequence owned it."""
-    from repro.kvcache.gather import (commit_token_writes,
-                                      scatter_prefill_plain,
+    from repro.kvcache.gather import (commit_token_writes, scatter_prefill,
                                       update_gather_plain)
 
     F, bt, K, hd, B = 6, 4, 2, 8, 3
     rng = np.random.default_rng(0)
-    k_slabs = jnp.asarray(rng.normal(size=(F, bt, K, hd)), jnp.float32)
-    v_slabs = jnp.asarray(rng.normal(size=(F, bt, K, hd)), jnp.float32)
+    # heads before frames: [K, F, bt, hd]
+    k_slabs = jnp.asarray(rng.normal(size=(K, F, bt, hd)), jnp.float32)
+    v_slabs = jnp.asarray(rng.normal(size=(K, F, bt, hd)), jnp.float32)
     k_new = jnp.asarray(rng.normal(size=(B, K, hd)), jnp.float32)
     v_new = jnp.asarray(rng.normal(size=(B, K, hd)), jnp.float32)
     # row 0 live in frame 2; rows 1-2 are padding (all -1 tables)
@@ -76,10 +76,10 @@ def test_padding_rows_never_write_device_kv():
 
     k2, v2, _, _ = update_gather_plain(k_slabs, v_slabs, k_new, v_new,
                                        phys, pos, bt)
-    assert jnp.array_equal(k2[2, 0], k_new[0])
+    assert jnp.array_equal(k2[:, 2, 0], k_new[0])
     # frames 0 and 1 (and everything but the live write) untouched
-    assert jnp.array_equal(k2[:2], k_slabs[:2])
-    assert jnp.array_equal(v2[:2], v_slabs[:2])
+    assert jnp.array_equal(k2[:, :2], k_slabs[:, :2])
+    assert jnp.array_equal(v2[:, :2], v_slabs[:, :2])
 
     # stacked-layer commit path
     L = 2
@@ -88,20 +88,19 @@ def test_padding_rows_never_write_device_kv():
     kn = jnp.asarray(rng.normal(size=(L, B, K, hd)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(L, B, K, hd)), jnp.float32)
     ks2, vs2 = commit_token_writes(k_stack, v_stack, kn, vn, phys, pos, bt)
-    assert jnp.array_equal(ks2[:, :2], k_stack[:, :2])
-    assert jnp.array_equal(vs2[:, :2], v_stack[:, :2])
-    assert jnp.array_equal(ks2[0, 2, 0], kn[0, 0])
+    assert jnp.array_equal(ks2[:, :, :2], k_stack[:, :, :2])
+    assert jnp.array_equal(vs2[:, :, :2], v_stack[:, :, :2])
+    assert jnp.array_equal(ks2[0, :, 2, 0], kn[0, 0])
 
     # prefill scatter: padding tokens are dropped, not clamped to frame 0
     S = 4
     kp = jnp.asarray(rng.normal(size=(B, S, K, hd)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(B, S, K, hd)), jnp.float32)
-    pos2 = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
-    kp2, vp2 = scatter_prefill_plain(k_slabs, v_slabs, kp, vp, phys, pos2,
-                                     bt)
-    assert jnp.array_equal(kp2[:2], k_slabs[:2])
-    assert jnp.array_equal(vp2[:2], v_slabs[:2])
-    assert jnp.array_equal(kp2[2], kp[0])
+    kp2, vp2 = scatter_prefill(k_stack, v_stack, jnp.stack([kp, vp]),
+                               jnp.stack([vp, kp]), phys)
+    assert jnp.array_equal(kp2[:, :, :2], k_stack[:, :, :2])
+    assert jnp.array_equal(vp2[:, :, :2], v_stack[:, :, :2])
+    assert jnp.array_equal(kp2[0, :, 2], jnp.swapaxes(kp[0], 0, 1))
 
 
 # ----------------------------------------------------------- pod locality

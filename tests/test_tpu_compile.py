@@ -10,6 +10,7 @@ library; every test stays in this one file so that one worker loads it.
 from __future__ import annotations
 
 import functools
+import math
 import os
 import re
 
@@ -22,7 +23,7 @@ from repro.kernels.expert_ffn.kernel import expert_ffn_kernel
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.kernels.pte_gather.kernel import pte_gather_kernel
-from repro.launch.specs import build_serve_step
+from repro.launch.specs import build_prefill_step, build_serve_step
 from repro.models import init_decode_state, init_params
 
 V5E_HBM_BYTES = 16 * 10**9
@@ -59,8 +60,8 @@ def test_paged_attention_compiles_for_v5e(spec):
     bf = jnp.bfloat16
     n_frames = BATCH * MAX_BLOCKS
     c = _compile(paged_attention_kernel, spec((BATCH, H, HD), bf),
-                 spec((n_frames, BT, K, HD), bf),
-                 spec((n_frames, BT, K, HD), bf),
+                 spec((K, n_frames, BT, HD), bf),
+                 spec((K, n_frames, BT, HD), bf),
                  spec((BATCH, MAX_BLOCKS), jnp.int32),
                  spec((BATCH,), jnp.int32))
     assert "tpu_custom_call" in c.as_text()
@@ -98,27 +99,49 @@ def test_expert_ffn_compiles_for_v5e(spec, rows):
     assert [re.sub(r"\.\d+$", "", n) for n in calls] == ["gmm"] * 3, calls
 
 
-def test_expert_decode_step_reads_the_stacked_experts(spec, monkeypatch):
-    """qwen3_moe_235b_a22b's one-chip decode step at its cell's shapes (64
-    rows, 129-block tables): the grouped-FFN kernel reads each layer's
-    experts from the 8-layer stack itself; no operation copies a layer's
-    experts out of it first (a scan's slice would be one, every step)."""
+# qwen3_moe_235b_a22b.reasoning_long: 64 rows of 129-block tables over a
+# pool of 64 * 129 frames; 4 KV heads, which fill half an (8, 128) tile
+EXPERT_BATCH, EXPERT_BLOCKS, EXPERT_PROMPT = 64, 129, 256
+
+
+@pytest.fixture(scope="module")
+def expert_steps(spec):
+    """qwen3_moe_235b_a22b's one-chip decode step at its cell's shapes,
+    its prefill at 8 of the cell's 64 rows (the same pool and stack), and
+    the decode state: compiled once for the tests below."""
     import repro.kernels.expert_ffn.ops as expert_ops
-    # the ops wrapper interprets the kernel where JAX's default backend is
-    # the CPU; here the step is compiled for the described chip
-    monkeypatch.setattr(expert_ops, "interpret_on_cpu", lambda: False)
     cfg = get_one_chip_config("qwen3_moe_235b_a22b")
-    batch, max_blocks = 64, 129
+    frames = EXPERT_BATCH * EXPERT_BLOCKS
     placed = functools.partial(jax.tree.map,
                                lambda l: spec(l.shape, l.dtype))
     params = placed(jax.eval_shape(functools.partial(init_params, cfg),
                                    spec((2,), jnp.uint32)))
-    state = placed(jax.eval_shape(functools.partial(
-        init_decode_state, cfg, batch, batch * max_blocks, max_blocks)))
-    hlo = _compile(build_serve_step(cfg), params, state,
-                   spec((batch,), jnp.int32),
-                   spec((batch, max_blocks), jnp.int32),
-                   donate_argnums=(1,)).as_text()
+
+    def state(batch):
+        return placed(jax.eval_shape(functools.partial(
+            init_decode_state, cfg, batch, frames, EXPERT_BLOCKS)))
+
+    # the ops wrapper interprets the kernel where JAX's default backend is
+    # the CPU; here the steps are compiled for the described chip
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(expert_ops, "interpret_on_cpu", lambda: False)
+        decode = _compile(build_serve_step(cfg), params, state(EXPERT_BATCH),
+                          spec((EXPERT_BATCH,), jnp.int32),
+                          spec((EXPERT_BATCH, EXPERT_BLOCKS), jnp.int32),
+                          donate_argnums=(1,))
+        prefill = _compile(build_prefill_step(cfg), params, state(8),
+                           spec((8, EXPERT_PROMPT), jnp.int32),
+                           spec((8, EXPERT_BLOCKS), jnp.int32),
+                           donate_argnums=(1,))
+    return decode, prefill, state(EXPERT_BATCH)
+
+
+def test_expert_decode_step_reads_the_stacked_experts(expert_steps):
+    """qwen3_moe_235b_a22b's one-chip decode step at its cell's shapes (64
+    rows, 129-block tables): the grouped-FFN kernel reads each layer's
+    experts from the 8-layer stack itself; no operation copies a layer's
+    experts out of it first (a scan's slice would be one, every step)."""
+    hlo = expert_steps[0].as_text()
     kernels = [l for l in hlo.splitlines()
                if re.search(r"%gmm\S* = .*tpu_custom_call", l)]
     assert len(kernels) == 3, kernels
@@ -126,6 +149,37 @@ def test_expert_decode_step_reads_the_stacked_experts(spec, monkeypatch):
         assert "bf16[64,4096,1536]" in l or "bf16[64,1536,4096]" in l, l
     one_layer = re.compile(r"= bf16\[8,(4096,1536|1536,4096)\]")
     assert not [l for l in hlo.splitlines() if one_layer.search(l)]
+
+
+def _large_copies(hlo: str, elements: int):
+    """(name, shape) of every ``copy`` in ``hlo``, fused or not, that moves
+    at least ``elements`` elements: a relayout of that much data."""
+    out = []
+    for m in re.finditer(r"%(copy\S*) = \w+\[([\d,]*)\]\S* copy\(", hlo):
+        dims = [int(d) for d in m[2].split(",") if d]
+        if math.prod(dims) >= elements:
+            out.append((m[1], m[2]))
+    return out
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_expert_steps_copy_no_kv_stack(expert_steps, step):
+    """With 4 KV heads the pool's old [F, bt, K, hd] minor dimensions left
+    the compiler a stack it relaid out whole every step (two such copies
+    before the decode step's layer scan, two of the gathered blocks in
+    every layer, 13.2 ms of a 35.7 ms step on a v5e).  Laid out heads
+    before frames, neither step copies a KV stack, a layer's pool or, in
+    the decode step, a layer's gathered blocks (as many elements as its
+    pool here), and the decode step's temporaries stay below one stack."""
+    compiled, state = expert_steps[0 if step == "decode" else 1], \
+        expert_steps[2]
+    stack = state.caches[0]["k_slabs"]
+    assert stack.shape == (8, 4, EXPERT_BATCH * EXPERT_BLOCKS, 16, 128)
+    pool_elements = math.prod(stack.shape[1:])
+    assert not _large_copies(compiled.as_text(), pool_elements)
+    if step == "decode":
+        stack_bytes = stack.size * stack.dtype.itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < stack_bytes
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill"])
@@ -142,8 +196,8 @@ def test_expert_steps_compile_on_an_expert_mesh(topo, monkeypatch, step):
 
     from repro.distributed.sharding import use_rules
     from repro.launch.mesh import make_mesh
-    from repro.launch.specs import (build_prefill_step, make_rules,
-                                    param_shardings, state_shardings)
+    from repro.launch.specs import (make_rules, param_shardings,
+                                    state_shardings)
     monkeypatch.setattr(expert_ops, "interpret_on_cpu", lambda: False)
     cfg = dataclasses.replace(get_one_chip_config("qwen3_moe_235b_a22b"),
                               n_layers=2, experts_held=16)
@@ -231,21 +285,26 @@ def _fusions(hlo: str):
 
 def test_decode_long_step_gathers_from_the_stack(spec):
     """At decode_long's shapes (16 rows, 129-block tables, 2,064 frames)
-    each layer's K and V gathers read the stacked cache itself: no fusion
-    of ``kv_gather`` copies a layer's pool out of the stack first."""
+    each layer's K and V gathers read the stacked cache itself, heads
+    before frames: no fusion of ``kv_gather`` copies a layer's pool out of
+    the stack first, and at most one layer's gathered blocks are relaid
+    out for the attention's dots."""
     batch, max_blocks = 16, 129
     frames = batch * max_blocks
     c, state = _decode_step(spec, batch, max_blocks)
     hlo = c.as_text()
-    pool = f"bf16[{frames},{BT},{K},{HD}]"
+    pool = f"bf16[{K},{frames},{BT},{HD}]"
     pool_copies = [(shape, name) for shape, name, ops in _fusions(hlo)
                    if "kv_gather/" in name and shape == pool
                    and "dynamic-slice" in ops]
     assert not pool_copies, pool_copies
+    blocks = f"{batch},{K},{max_blocks},{BT},{HD}"
     gathers = re.findall(
-        rf"= bf16\[{batch},{max_blocks},{BT},{K},{HD}\]\S* gather\("
+        rf"= bf16\[{blocks}\]\S* gather\("
         r"[^\n]*op_name=\"[^\"]*kv_gather/gather\"", hlo)
     assert len(gathers) == 2, gathers          # one for K, one for V
+    relayouts = re.findall(rf"= bf16\[{blocks}\]\S* copy\(", hlo)
+    assert len(relayouts) <= 1, relayouts
     mem = c.memory_analysis()
     pool_bytes = frames * BT * K * HD * jnp.dtype(jnp.bfloat16).itemsize
     assert mem.temp_size_in_bytes < 2 * pool_bytes

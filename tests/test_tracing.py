@@ -95,13 +95,13 @@ def test_kv_gather_and_commit_are_scoped():
     """Every read of KV blocks out of the cache is ``kv_gather``; every
     write of the decode step into the stacked cache is ``kv_commit``."""
     cfg = get_smoke_config("qwen3_14b")
-    block = f"{cfg.kv_block_tokens},{cfg.n_kv_heads},{cfg.resolved_head_dim}"
-    stack = f"{cfg.n_layers},{FRAMES},{block}"
+    block = f"{cfg.kv_block_tokens},{cfg.resolved_head_dim}"
+    stack = f"{cfg.n_layers},{cfg.n_kv_heads},{FRAMES},{block}"
     ops = _ops(_optimized_hlo("decode"))
     gathers = [n for op, shape, n in ops
                if op == "gather" and shape.endswith(block)]
     commits = [n for op, shape, n in ops
-               if op == "dynamic-update-slice" and shape == stack]
+               if op == "scatter" and shape == stack]
     assert gathers and commits
     assert all("/kv_gather/" in n for n in gathers), gathers
     assert all("/kv_commit/" in n for n in commits), commits
